@@ -145,12 +145,14 @@ def fig_scaling(steps: int = 2, grid="8,8,8", policy="unified"):
     (repro.core.shard_program + repro.launch.scaling), strong- AND
     weak-scaling, under the overlapped wide-halo exchange schedule.
 
-    Each node size runs in a fresh subprocess — the APU count must be in
-    XLA_FLAGS before the first jax import, and this process has already
-    imported jax with one device.  Every run asserts single- vs
-    multi-device numerical parity (docs/DESIGN.md §2 tolerance) and the
-    derived column carries the node-level compute/staging/exchange/overlap
-    split from the aggregated per-device ledgers.  On a CPU container all
+    On forced CPU devices each node size runs in a fresh subprocess — the
+    APU count must be in XLA_FLAGS before the first jax import, and this
+    process has already imported jax with one device; on real chips it
+    runs in this process (``repro.launch.scaling.run``).  Every run
+    asserts single- vs multi-device numerical parity (docs/DESIGN.md §2
+    tolerance) and the derived column carries the node-level
+    compute/staging/exchange/overlap split from the aggregated
+    per-device ledgers.  On a CPU container all
     "APUs" share the same cores, so the FOM here is the exchange
     accounting and the parity guarantee, not wall-clock speedup (see
     docs/SCALING.md).
@@ -162,8 +164,8 @@ def fig_scaling(steps: int = 2, grid="8,8,8", policy="unified"):
     FIG_SCALING_GRID=16,16,16 FIG_SCALING_SCHEDULE=overlap|sequential|split
     FIG_SCALING_HALO=2 FIG_SCALING_MESH=auto|1d FIG_SCALING_BUDGET=0.15."""
     import os
-    import subprocess
-    import sys
+
+    from repro.launch import scaling
     apus = [int(x) for x in
             os.environ.get("FIG_SCALING_APUS", "1,2,4,8").split(",") if x]
     grid = os.environ.get("FIG_SCALING_GRID", grid)
@@ -176,21 +178,17 @@ def fig_scaling(steps: int = 2, grid="8,8,8", policy="unified"):
         mesh_shape = _scaling_mesh_shape(n)
         out = Path(f"artifacts/scaling/{out_name}.json")
         out.parent.mkdir(parents=True, exist_ok=True)
-        cmd = [sys.executable, "-m", "repro.launch.scaling",
-               "--apus", str(n), "--mesh",
-               "x".join(str(s) for s in mesh_shape),
-               "--steps", str(steps),
-               "--grid", ",".join(str(g) for g in grid_t),
-               "--policy", policy, "--schedule", schedule,
-               "--halo-multiplier", halo_mult,
-               "--inner-max", "6", "--out", str(out)]
-        r = subprocess.run(cmd, capture_output=True, text=True)
-        if r.returncode != 0:
-            row(row_name, 0.0,
-                f"FAILED rc={r.returncode}:{r.stderr.strip()[-160:]}")
-            raise RuntimeError(f"fig_scaling subprocess failed for "
-                               f"{n} APUs:\n{r.stderr[-2000:]}")
-        rec = json.loads(out.read_text())
+        argv = ["--apus", str(n), "--mesh",
+                "x".join(str(s) for s in mesh_shape),
+                "--steps", str(steps),
+                "--grid", ",".join(str(g) for g in grid_t),
+                "--policy", policy, "--schedule", schedule,
+                "--halo-multiplier", halo_mult, "--inner-max", "6"]
+        try:
+            rec = scaling.run(argv, out)
+        except RuntimeError as e:
+            row(row_name, 0.0, f"FAILED:{str(e).strip()[-160:]}")
+            raise RuntimeError(f"fig_scaling failed for {n} APUs") from e
         assert rec["parity_ok"], rec          # acceptance criterion
         rep = rec["report"]
         dev0 = rep["per_device"][0]

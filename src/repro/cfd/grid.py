@@ -75,10 +75,15 @@ def shift(f, axis: int, direction: int):
 
 
 def interior_mask(grid: Grid, axis: int, direction: int):
-    """1.0 where the neighbor in (axis, direction) exists."""
-    nx, ny, nz = grid.shape
-    m = np.ones(grid.shape, np.float32)
-    sl = [slice(None)] * 3
-    sl[axis] = 0 if direction < 0 else grid.shape[axis] - 1
-    m[tuple(sl)] = 0.0
-    return jnp.asarray(m)
+    """1.0 where the neighbor in (axis, direction) exists.
+
+    A broadcast of one mask along ``axis``: inside a traced region it stays
+    an n-element iota, where a grid-sized numpy mask became a literal
+    constant that XLA folded every operator built from it into (hundreds
+    of MB of program at 256x256x128)."""
+    n = grid.shape[axis]
+    edge = 0 if direction < 0 else n - 1
+    line = (jnp.arange(n) != edge).astype(jnp.float32)
+    shape = [1, 1, 1]
+    shape[axis] = n
+    return jnp.broadcast_to(line.reshape(shape), grid.shape)

@@ -180,7 +180,7 @@ class SimpleFoam:
             Pp = RBDilu(rdiag_p, self.red)
             res_p = pbicgstab_regions(ex, self.solver_regions,
                                       DiaMatrix(dp, offp), rp,
-                                      jnp.zeros_like(rp), Pp,
+                                      jnp.zeros(rp.shape, rp.dtype), Pp,
                                       tol=cfg.tol_p, max_iter=cfg.inner_max)
             p_corr = res_p.x
             # --- momentum corrector ----------------------------------

@@ -123,8 +123,9 @@ def pbicgstab_regions(executor, regions, A: DiaMatrix, b, x0, P: RBDilu,
     res0 = float(run(regions.summag, r)) / norm
     res = res0
     rho_old = alpha = omega = 1.0
-    p = jnp.zeros_like(b)
-    v = jnp.zeros_like(b)
+    # zeros in device memory whatever space b was staged into
+    p = jnp.zeros(b.shape, b.dtype)
+    v = jnp.zeros(b.shape, b.dtype)
     it = 0
     while res > tol and (rel_tol <= 0 or res / max(res0, SMALL) > rel_tol) \
             and it < max_iter:

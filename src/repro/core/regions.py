@@ -75,6 +75,27 @@ def accel_device():
     return accel[0] if accel else jax.devices()[0]
 
 
+def _to_host(tree):
+    """Commit every array operand of ``tree`` to the host CPU device: a
+    jitted call runs where its committed operands live, so routing a call
+    to ``host`` means moving them there."""
+    dev = host_device()
+    sh = jax.sharding.SingleDeviceSharding(dev)
+
+    def move(x):
+        if isinstance(x, jax.Array) and x.devices() != {dev}:
+            return jax.device_put(x, sh)
+        return x
+    return jax.tree.map(move, tree)
+
+
+def _host_placed(tree) -> bool:
+    """Does any array leaf of ``tree`` live in a host memory space?"""
+    return any(umem.space_of(x) in (MemSpace.HOST.kind,
+                                    MemSpace.HOST_UNPINNED.kind)
+               for x in jax.tree.leaves(tree) if isinstance(x, jax.Array))
+
+
 def _param_indices(fn: Callable) -> Dict[str, int]:
     """Positional index of each named parameter, so placement hints keyed
     by name apply to positionally-passed arguments too."""
@@ -265,7 +286,8 @@ class Region:                           # hashable, usable as dict/set keys
 
     # -- per-(target, variant) compiled executables ----------------------
     def _jit(self, fn: Callable) -> Callable:
-        return jax.jit(fn, donate_argnums=tuple(self.donate_args or ()))
+        return jax.jit(umem.device_operands(fn),
+                       donate_argnums=tuple(self.donate_args or ()))
 
     @property
     def jitted(self):
@@ -293,7 +315,7 @@ class Region:                           # hashable, usable as dict/set keys
             elif dflag:
                 j = self._jit(self.impl_fn(name))
             else:
-                j = jax.jit(self.impl_fn(name))
+                j = jax.jit(umem.device_operands(self.impl_fn(name)))
             self._jvar[key] = j
         return j
 
@@ -319,10 +341,13 @@ class Region:                           # hashable, usable as dict/set keys
             jfn = self.jitted_variant(impl, donate=donate)
             if target == "default":
                 call = jfn
+            elif target == "host":
+                def call(*args, _jfn=jfn, **kwargs):
+                    args, kwargs = _to_host((args, kwargs))
+                    with jax.default_device(host_device()):
+                        return _jfn(*args, **kwargs)
             else:
-                dev = host_device() if target == "host" else accel_device()
-
-                def call(*args, _jfn=jfn, _dev=dev, **kwargs):
+                def call(*args, _jfn=jfn, _dev=accel_device(), **kwargs):
                     with jax.default_device(_dev):
                         return _jfn(*args, **kwargs)
 
@@ -484,8 +509,9 @@ class NullStager:
 # buffer's storage, which is what "reuse" means for immutable arrays
 # (select keeps the dtype exact — src and dst match by construction).
 # Module-level so every stager shares one jit cache per shape/dtype.
-_copy_into = jax.jit(lambda src, dst: jnp.where(True, src, dst),
-                     donate_argnums=(1,))
+_copy_into = jax.jit(
+    umem.device_operands(lambda src, dst: jnp.where(True, src, dst)),
+    donate_argnums=(1,))
 
 # slab-into-donated-buffer: the chunked form of _copy_into for
 # budget-bounded staging — lands one leading-axis slab of the source in
@@ -493,8 +519,9 @@ _copy_into = jax.jit(lambda src, dst: jnp.where(True, src, dst),
 # staging granule streams through it in slabs instead of migrating as
 # one transient allocation.
 _copy_slab = jax.jit(
-    lambda dst, src, start: jax.lax.dynamic_update_slice_in_dim(
-        dst, src, start, axis=0),
+    umem.device_operands(
+        lambda dst, src, start: jax.lax.dynamic_update_slice_in_dim(
+            dst, src, start, axis=0)),
     donate_argnums=(0,))
 
 
@@ -1043,9 +1070,11 @@ class Executor:
             staging_b += b
         t0 = time.perf_counter()
         # donation is disabled under staging policies: staged operands may
-        # alias pooled pages whose lifetime the stager manages
-        out = r.executable(tgt, impl,
-                           donate=not pol.stager.stages)(*args, **kwargs)
+        # alias pooled pages whose lifetime the stager manages; and a
+        # host-placed operand cannot back a result in device memory
+        donate = not pol.stager.stages and not _host_placed(
+            [args[i] for i in (r.donate_args or ()) if i < len(args)])
+        out = r.executable(tgt, impl, donate=donate)(*args, **kwargs)
         jax.block_until_ready(out)
         compute_s = time.perf_counter() - t0
         if stage:

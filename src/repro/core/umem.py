@@ -13,7 +13,8 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Any, Optional
+import functools
+from typing import Any, Callable, Optional
 
 import jax
 
@@ -89,6 +90,25 @@ def tree_place(tree, space: MemSpace, device=None, min_bytes: int = 0):
             return x
         return place(x, space, device)
     return jax.tree.map(maybe, tree)
+
+
+def device_operands(fn: Callable) -> Callable:
+    """Wrap ``fn`` (to be jitted) so that, inside the trace, every array
+    operand typed as host memory is first moved into device memory.  A
+    jitted computation may take ``pinned_host`` operands, but the TPU
+    compiler refuses to compute on them in place, so a region fetches its
+    host-placed operands at entry; the copy is part of the program."""
+    def fetch(x):
+        if isinstance(x, jax.Array) and getattr(
+                jax.typeof(x), "memory_space", None) == jax.memory.Space.Host:
+            return jax.device_put(x, jax.memory.Space.Device)
+        return x
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        args, kwargs = jax.tree.map(fetch, (args, kwargs))
+        return fn(*args, **kwargs)
+    return wrapped
 
 
 def tree_place_budgeted(tree, budget, device=None, min_bytes: int = 0,

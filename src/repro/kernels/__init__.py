@@ -2,8 +2,8 @@
 
 Each subpackage ``<name>/`` is one compute hot-spot with three files:
 
-* ``kernel.py`` — the Pallas implementation (interpret mode on CPU
-  containers; flip ``_INTERPRET`` on real hardware),
+* ``kernel.py`` — the Pallas implementation (compiled by Mosaic when
+  lowered for a TPU, interpreted on any other platform: ``_call.py``),
 * ``ref.py``    — the pure-jnp oracle: the **ref** variant and the
   semantics anchor every other variant is tested against,
 * ``ops.py``    — jitted public wrappers (used by the kernel's own tests).

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels._call import pallas_call
+
 LANES = 128
 BLOCK_ROWS = 256            # 256x128 f32 tile = 128 KiB VMEM per operand
 
@@ -67,18 +69,14 @@ def _run_elementwise(kernel, scalars, arrays, out_dtype):
     for t in tiled:
         in_specs.append(block)
         args.append(t)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
-        interpret=_INTERPRET,
     )(*args)
     return out.reshape(-1)[: n[0]].reshape(x0.shape)
-
-
-_INTERPRET = True       # CPU container: interpret mode; flip on real TPU
 
 
 def fused_axpy(a, x, y):

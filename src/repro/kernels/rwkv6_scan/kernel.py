@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_INTERPRET = True
+from repro.kernels._call import pallas_call
+
 CHUNK = 64
 
 
@@ -41,7 +42,12 @@ def _kernel(C, hd, r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref,
     u = u_ref[0].astype(jnp.float32)          # [1, hd] -> broadcast
     S = state_ref[...]
 
-    la = jnp.cumsum(lw, axis=0)               # [C, hd]
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (cumsum has no Mosaic lowering)
+    tril = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0) >=
+            jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)).astype(jnp.float32)
+    la = jnp.dot(tril, lw, preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)   # [C, hd]
     la_prev = la - lw
     rA = r * jnp.exp(la_prev)
     inter = rA @ S                             # [C, hd_v]
@@ -84,7 +90,7 @@ def rwkv6_scan(r, k, v, logw, u, chunk: int = CHUNK):
 
     io_spec = pl.BlockSpec((1, C, hd), lambda b, c: (b, c, 0))
     u_spec = pl.BlockSpec((1, 1, hd), lambda b, c: (b, 0, 0))
-    out, s_final = pl.pallas_call(
+    out, s_final = pallas_call(
         functools.partial(_kernel, C, hd),
         grid=(B * H, nc),
         in_specs=[io_spec, io_spec, io_spec, io_spec, u_spec],
@@ -93,7 +99,6 @@ def rwkv6_scan(r, k, v, logw, u, chunk: int = CHUNK):
         out_shape=[jax.ShapeDtypeStruct((B * H, T, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=_INTERPRET,
     )(rb, kb, vb, lwb, ub)
     out = jnp.moveaxis(out.reshape(B, H, T, hd), 1, 2)
     return out, s_final.reshape(B, H, hd, hd)

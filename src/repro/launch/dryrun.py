@@ -304,7 +304,10 @@ def sweep(out_dir: str, multi_pod_also=True, timeout=2400):
             cmd.append("--multi-pod")
         print(f"[sweep] {path.stem}", flush=True)
         try:
-            subprocess.run(cmd, timeout=timeout, check=False)
+            # the dry run compiles for forced CPU devices: the child never
+            # needs (or contends for) an accelerator this process may hold
+            subprocess.run(cmd, timeout=timeout, check=False,
+                           env={**os.environ, "JAX_PLATFORMS": "cpu"})
         except subprocess.TimeoutExpired:
             _write({"arch": a, "shape": sname, "multi_pod": mp,
                     "status": "timeout", "timeout_s": timeout}, path)

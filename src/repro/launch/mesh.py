@@ -16,20 +16,29 @@ reduction over ("pod","data") is therefore hierarchical by construction.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+def _mesh(shape, axes, what: str, hint: str):
+    """The one mesh constructor: the first ``prod(shape)`` devices, every
+    axis ``Auto`` so the model's ``with_sharding_constraint`` calls
+    (``launch/sharding.py``) may name any mesh axis."""
     n = 1
     for s in shape:
         n *= s
     devices = jax.devices()
     if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devices)}; "
-            "the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+        raise RuntimeError(f"need {n} devices for {what} {shape}, have "
+                           f"{len(devices)}; {hint}")
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    shape = (2, 16, 16) if multi_pod else (16, 16)
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return _mesh(shape, axes, "mesh", "the dry-run sets "
+                 "XLA_FLAGS=--xla_force_host_platform_device_count=512")
 
 
 def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
@@ -40,12 +49,8 @@ def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for smoke mesh {shape}, have {len(devices)}; "
-            f"set XLA_FLAGS={apu_flags(n)} before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _mesh(shape, axes, "smoke mesh",
+                 f"set XLA_FLAGS={apu_flags(n)} before importing jax")
 
 
 def apu_flags(n_apus: int) -> str:
@@ -103,10 +108,6 @@ def make_apu_mesh(n_apus=1, axis: str = "apu"):
     n = 1
     for s in shape:
         n *= s
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for a {shape} APU mesh, have "
-            f"{len(devices)}; set XLA_FLAGS={apu_flags(n)} before "
-            "importing jax (see docs/SCALING.md)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _mesh(shape, axes, "an APU mesh",
+                 f"set XLA_FLAGS={apu_flags(n)} before importing jax "
+                 "(see docs/SCALING.md)")

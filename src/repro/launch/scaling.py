@@ -17,11 +17,13 @@ don't divide over the mesh are padded up to the next multiple
 (remainder-row padding — both replays run the padded grid, so parity
 stays meaningful).
 
-Each invocation must own its process: the APU count is baked into
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* the first
-jax import (the ``launch.dryrun`` trick), so the benchmark harness
-(``benchmarks/run.py fig_scaling``) runs this module once per node size in
-a subprocess:
+On forced CPU devices each invocation must own its process: the APU
+count is baked into ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+*before* the first jax import (the ``launch.dryrun`` trick). On real chips
+the flag changes nothing, and a child process could not reach chips its
+parent holds. :func:`run` makes that choice for callers that have already
+imported jax (``benchmarks/run.py fig_scaling``, the tuner's
+``cfd_sharded``):
 
   PYTHONPATH=src python -m repro.launch.scaling --apus 4 --mesh 2x2 \\
       --steps 2 --grid 16,16,16 --policy unified \\
@@ -32,6 +34,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
 import sys
 from pathlib import Path
 
@@ -107,7 +110,10 @@ def main(argv=None) -> dict:
     from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
     from repro.core.regions import Executor, StaticSelector, make_policy
     from repro.core.shard_program import shard_program
+    from repro.launch.compilation import configure_compilation
     from repro.launch.mesh import make_apu_mesh, parse_mesh_shape
+
+    configure_compilation()
 
     tuned_cell = None
     if args.policy == "auto":
@@ -206,6 +212,10 @@ def main(argv=None) -> dict:
         "parity_max_abs_err": max_err,
         "parity_tol": tol,
         "parity_ok": bool(max_err <= tol),
+        # where the decomposed replay's fields live: how many devices hold
+        # a shard, and the shape of one shard
+        "field_devices": len(s_sh.u.devices()),
+        "field_shard_shape": list(s_sh.u.addressable_shards[0].data.shape),
         "halo_rows": sorted(n for n in sp.ledgers[0].regions
                             if n.startswith("halo(")),
         "report": rep,
@@ -223,6 +233,27 @@ def main(argv=None) -> dict:
     if not rec["parity_ok"]:
         raise SystemExit(2)
     return rec
+
+
+def run(argv, out) -> dict:
+    """One scaling measurement, written to ``out`` and returned, from a
+    process that has imported jax: in this process when the devices are
+    accelerator chips, in a child process when they are forced CPU devices
+    (the child sets the device count before its own jax import). Raises
+    RuntimeError when the measurement fails."""
+    import jax
+    argv = [*argv, "--out", str(out)]
+    if jax.devices()[0].platform != "cpu":
+        try:
+            return main(argv)
+        except SystemExit as e:
+            raise RuntimeError(f"scaling run exited with {e.code}") from e
+    r = subprocess.run([sys.executable, "-m", "repro.launch.scaling", *argv],
+                       capture_output=True, text=True)
+    if r.returncode != 0:
+        raise RuntimeError(f"scaling subprocess rc={r.returncode}:\n"
+                           f"{r.stderr[-2000:]}")
+    return json.loads(Path(out).read_text())
 
 
 if __name__ == "__main__":

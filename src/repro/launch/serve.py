@@ -42,8 +42,11 @@ from repro.configs.reduced import reduced as make_reduced
 from repro.configs.registry import get_config
 from repro.core.ledger import Ledger
 from repro.core.program import AsyncExecutor, capture
-from repro.core.regions import Executor, Placer, UnifiedPolicy, region
-from repro.core.umem import MemSpace, preferred_host_space, tree_place
+from repro.core.regions import (Executor, Placer, UnifiedPolicy,
+                                default_size, region)
+from repro.core.umem import (MemSpace, device_operands,
+                             preferred_host_space, space_of, tree_place)
+from repro.launch.compilation import configure_compilation
 from repro.launch import sharding as SH
 from repro.launch.mesh import make_smoke_mesh
 from repro.launch.policy import PLACER_MIN_BYTES, POLICY_CHOICES, lm_policy
@@ -99,6 +102,18 @@ class KVCachePlacer(Placer):
         return place_kv_leaves(out, self.kv_space, self.kv_min_bytes)
 
 
+def kv_spaces(tree) -> set:
+    """Memory kinds the ``k``/``v``-keyed leaves of a cache tree live in."""
+    out = set()
+
+    def per_leaf(path, x):
+        if {getattr(p, "key", None) for p in path} & set(KV_PLACE_KEYS):
+            out.add(space_of(x))
+        return x
+    jax.tree_util.tree_map_with_path(per_leaf, tree)
+    return out
+
+
 def offload_kv_cache(space: Optional[MemSpace] = None,
                      min_bytes: int = KV_PLACE_MIN_BYTES) -> KVCachePlacer:
     """The ``--offload-kv`` Placer: role-keyed KV offload to host DRAM
@@ -114,26 +129,36 @@ def offload_kv_cache(space: Optional[MemSpace] = None,
 
 @dataclasses.dataclass
 class ServeRegions:
-    """The request path as directive-sized regions (params closed over)."""
-    prefill: Any        # (batch, cache)    -> (tok, cache)
-    decode_step: Any    # (tok, cache, pos) -> (tok, cache)
-    kv_append: Any      # (cache,)          -> cache
+    """The request path as directive-sized regions; the model regions take
+    ``params`` as their first argument."""
+    params: Any
+    prefill: Any        # (params, batch, cache)    -> (tok, cache)
+    decode_step: Any    # (params, tok, cache, pos) -> (tok, cache)
+    kv_append: Any      # (cache,)                  -> cache
+
+
+def _state_size(args, kwargs) -> int:
+    """Problem size of a model region: its request state, not its weights
+    (the routing clause must see the batch, not the embedding table)."""
+    return default_size(args[1:], kwargs)
 
 
 def make_serve_regions(cfg, mesh, params, *, ledger: Optional[Ledger] = None,
                        q_chunk: int = 256) -> ServeRegions:
     """``PREFILL`` / ``DECODE_STEP`` / ``KV_APPEND`` on one ledger.
 
-    ``params`` are closed over (constants), which is exactly what
-    ``replay_batch`` wants: under ``vmap`` they broadcast across the N
-    stacked requests while tokens and caches batch.  ``KV_APPEND`` is the
-    cache *commit* directive: the model's fused insert runs inside
-    ``DECODE_STEP`` (attention appends as it attends), and this
-    math-identity region is where the policy's placement axis re-homes the
-    appended pages (role-keyed ``--offload-kv``) and the ledger accounts
-    the per-token cache commit.  ``offloaded=False``: commitment is
-    bookkeeping, not a staged offload — no policy stages the whole cache
-    twice per token.
+    ``params`` are an argument of every model region, never closed over:
+    jit embeds a closed-over array in the program as a literal constant,
+    gigabytes per program at published widths.  Captured programs pass
+    them as a constant input, which is what ``replay_batch`` wants: under
+    ``vmap`` they broadcast across the N stacked requests while tokens and
+    caches batch.  ``KV_APPEND`` is the cache *commit* directive: the
+    model's fused insert runs inside ``DECODE_STEP`` (attention appends as
+    it attends), and this math-identity region is where the policy's
+    placement axis re-homes the appended pages (role-keyed
+    ``--offload-kv``) and the ledger accounts the per-token cache commit.
+    ``offloaded=False``: commitment is bookkeeping, not a staged offload —
+    no policy stages the whole cache twice per token.
     """
     rules = SH.ShardingRules("serve")
     shd = SH.make_sharder(mesh, rules)
@@ -143,13 +168,13 @@ def make_serve_regions(cfg, mesh, params, *, ledger: Optional[Ledger] = None,
     raw_decode = S.make_decode_step(
         cfg, lambda: T.Ctx(mode="decode", shd=shd, remat=False))
 
-    @region("PREFILL", ledger=ledger)
-    def prefill_region(batch, cache):
+    @region("PREFILL", ledger=ledger, size_fn=_state_size)
+    def prefill_region(params, batch, cache):
         logits, cache = raw_prefill(params, batch, cache)
         return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
 
-    @region("DECODE_STEP", ledger=ledger)
-    def decode_region(tok, cache, pos):
+    @region("DECODE_STEP", ledger=ledger, size_fn=_state_size)
+    def decode_region(params, tok, cache, pos):
         logits, cache = raw_decode(params, tok, cache, pos)
         return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
 
@@ -161,8 +186,8 @@ def make_serve_regions(cfg, mesh, params, *, ledger: Optional[Ledger] = None,
     def kv_append(cache):
         return cache
 
-    return ServeRegions(prefill=prefill_region, decode_step=decode_region,
-                        kv_append=kv_append)
+    return ServeRegions(params=params, prefill=prefill_region,
+                        decode_step=decode_region, kv_append=kv_append)
 
 
 def capture_prefill_program(regions: ServeRegions, example_batch,
@@ -170,7 +195,7 @@ def capture_prefill_program(regions: ServeRegions, example_batch,
     """Prefill as a RegionProgram: one ``PREFILL`` call, then the
     ``KV_APPEND`` commit of the prompt's cache pages."""
     def prefill_fn(run, batch, cache):
-        tok, cache = run(regions.prefill, batch, cache)
+        tok, cache = run(regions.prefill, regions.params, batch, cache)
         cache = run(regions.kv_append, cache)
         return tok, cache
 
@@ -190,8 +215,8 @@ def capture_decode_program(regions: ServeRegions, prompt_len: int, gen: int,
     def gen_loop(run, tok, cache):
         toks = [tok]
         for i in range(gen - 1):
-            tok, cache = run(regions.decode_step, tok, cache,
-                             jnp.int32(prompt_len + i))
+            tok, cache = run(regions.decode_step, regions.params, tok,
+                             cache, jnp.int32(prompt_len + i))
             cache = run(regions.kv_append, cache)
             toks.append(tok)
         return tuple(toks)      # tuple of refs (stacking outside a region
@@ -208,11 +233,11 @@ def build_server(cfg, mesh, batch: int, max_len: int, q_chunk=256,
                  offload_kv=False):
     rules = SH.ShardingRules("serve")
     shd = SH.make_sharder(mesh, rules)
-    prefill = jax.jit(S.make_prefill_step(
+    prefill = jax.jit(device_operands(S.make_prefill_step(
         cfg, lambda: T.Ctx(mode="prefill", shd=shd, q_chunk=q_chunk,
-                           remat=False)))
-    decode = jax.jit(S.make_decode_step(
-        cfg, lambda: T.Ctx(mode="decode", shd=shd, remat=False)),
+                           remat=False))))
+    decode = jax.jit(device_operands(S.make_decode_step(
+        cfg, lambda: T.Ctx(mode="decode", shd=shd, remat=False))),
         donate_argnums=(2,))
 
     # KV placement is a MemSpace hint, not a hand-rolled sharding: pages big
@@ -348,12 +373,15 @@ def _verify_programs(ex, *progs):
 def _engine_demo(cfg, mesh, params, ex, args, max_len):
     """Continuous-batching engine under the launcher flags: seeded Poisson
     traffic with ragged prompt/gen lengths through
-    :class:`repro.serve.ServeEngine`, bit-parity asserted against solo jit
-    decodes of the same prompts (docs/SERVING.md)."""
+    :class:`repro.serve.ServeEngine`, each token checked against the solo
+    jit path's logits (``assert_logit_parity``, docs/SERVING.md).  Returns
+    the traffic metrics, each request's tokens (``outputs``), the memory
+    kinds of the slot cache's k/v pages (``kv_spaces``) and the parity
+    check's counts (``parity``)."""
     # lazy import: repro.serve runs ON this module's regions and programs
     from repro.serve import (PagedKVCache, ServeEngine, make_traffic,
                              run_traffic, solo_reference)
-    from repro.serve.traffic import assert_parity
+    from repro.serve.traffic import LOGIT_TOL_ULPS, assert_logit_parity
 
     budget = None
     if args.kv_oversub_ratio > 0:
@@ -384,7 +412,10 @@ def _engine_demo(cfg, mesh, params, ex, args, max_len):
     metrics = run_traffic(engine, reqs)
     oracle, solo_wall = solo_reference(cfg, mesh, params, reqs, max_len,
                                        offload_kv=args.offload_kv)
-    assert_parity(reqs, oracle)        # the acceptance invariant
+    # the acceptance invariant: every engine token is the solo path's top
+    # logit, teacher-forced, up to a stated bf16 tolerance
+    par = assert_logit_parity(cfg, mesh, params, reqs, oracle, max_len,
+                              offload_kv=args.offload_kv)
     solo_tps = metrics["tokens"] / max(solo_wall, 1e-9)
     st = kv.stats
     spill_note = (f"; {st.pages_spilled} pages spilled to host"
@@ -404,10 +435,17 @@ def _engine_demo(cfg, mesh, params, ex, args, max_len):
           f"p50 {metrics.get('p50_token_ms', 0.0):.2f} / p99 "
           f"{metrics.get('p99_token_ms', 0.0):.2f} ms/token; KV page "
           f"high-water {st.device_high_water_bytes} B device"
-          f"{spill_note}{evict_note}; parity OK vs solo jit")
+          f"{spill_note}{evict_note}; parity OK vs solo jit: "
+          f"{par['tokens']} tokens within {LOGIT_TOL_ULPS} bf16 spacings of "
+          f"the teacher-forced solo top logit (max {par['gap_max']:.1f}), "
+          f"{par['diverged']}/{len(reqs)} streams left the free-running "
+          f"solo decode; slot KV in "
+          f"{'/'.join(sorted(kv_spaces(engine.slot_cache)))}")
     if args.report:
         print(json.dumps(ex.report(), indent=1, default=str))
-    return metrics
+    return {**metrics, "outputs": {r.req_id: list(r.tokens) for r in reqs},
+            "kv_spaces": sorted(kv_spaces(engine.slot_cache)),
+            "parity": par}
 
 
 def main(argv=None):
@@ -440,8 +478,8 @@ def main(argv=None):
     ap.add_argument("--engine", action="store_true",
                     help="continuous-batching engine instead of the static "
                          "batch: Poisson traffic through slot-scheduled "
-                         "decode over a paged KV cache, bit-parity "
-                         "asserted vs solo jit decodes (docs/SERVING.md); "
+                         "decode over a paged KV cache, every token "
+                         "checked against solo jit logits (docs/SERVING.md); "
                          "composes with any --policy and --offload-kv")
     ap.add_argument("--slots", type=int, default=4, metavar="N",
                     help="engine decode slots (the vmapped tick width)")
@@ -476,6 +514,7 @@ def main(argv=None):
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N before launch, see docs/SCALING.md")
     args = ap.parse_args(argv)
+    configure_compilation()
     if args.mesh and not args.replay_batch:
         raise SystemExit("--mesh requires --replay-batch N (it shards the "
                          "batched decode program)")

@@ -37,6 +37,7 @@ from repro.core.ledger import Ledger
 from repro.core.regions import Executor
 from repro.core.umem import place_like
 from repro.data.pipeline import ShardInfo, make_source
+from repro.launch.compilation import configure_compilation
 from repro.launch import sharding as SH
 from repro.launch.mesh import make_smoke_mesh
 from repro.launch.policy import POLICY_CHOICES, lm_policy
@@ -162,6 +163,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--fail-at", default="", help="fault injection steps, csv")
     args = ap.parse_args(argv)
+    configure_compilation()
 
     cfg = get_config(args.arch)
     if args.reduced:

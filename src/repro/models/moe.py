@@ -244,7 +244,11 @@ class ExpertPager:
         host = host_space or umem.preferred_host_space()
         self.router = p["router"]              # device-resident by design
         self.shared = p.get("shared")
-        self._host = {k: umem.place(p[k], host) if host is not None else p[k]
+        # one host array per expert slab, sliced while still on the device:
+        # a fetch then moves exactly one slab (slicing a host-space stack
+        # is itself a computation on host memory)
+        self._host = {k: [umem.place(p[k][e], host) if host is not None
+                          else p[k][e] for e in range(p[k].shape[0])]
                       for k in EXPERT_KEYS}
         self.slab_bytes = sum(int(p[k][0].nbytes) for k in EXPERT_KEYS)
         self._resident: Dict[int, dict] = {}   # expert id -> slab (LRU order)

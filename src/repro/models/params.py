@@ -54,9 +54,14 @@ def init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
         n = int(np.prod(spec.shape)) if spec.shape else 1
         ramp = jnp.linspace(-6.0, 1.0, n).reshape(spec.shape or ())
         return ramp.astype(spec.dtype)
-    fan_in = spec.shape[0] if len(spec.shape) >= 2 else max(spec.shape[-1] if spec.shape else 1, 1)
-    scale = spec.scale if spec.scale is not None else 1.0 / np.sqrt(fan_in)
+    scale = spec.scale if spec.scale is not None else fan_in_scale(spec.shape)
     return (jax.random.normal(key, spec.shape, jnp.float32) * scale).astype(spec.dtype)
+
+
+def fan_in_scale(shape: Tuple[int, ...]) -> float:
+    """The default init scale 1/sqrt(fan_in) of one weight of ``shape``."""
+    fan_in = shape[0] if len(shape) >= 2 else max(shape[-1] if shape else 1, 1)
+    return 1.0 / float(np.sqrt(fan_in))
 
 
 def init_params(key: jax.Array, specs):
@@ -73,10 +78,13 @@ def abstract_params(specs):
 
 
 def stack_specs(specs, n: int, axis_name: str = "layers"):
-    """Add a leading stacking dimension (for lax.scan over layers)."""
+    """Add a leading stacking dimension (for lax.scan over layers).  The
+    default init scale is resolved per layer first: the stacking axis is
+    not a fan-in."""
     leaves, treedef = tree_specs(specs)
     stacked = [
-        ParamSpec((n,) + s.shape, (axis_name,) + s.axes, s.dtype, s.init, s.scale)
+        ParamSpec((n,) + s.shape, (axis_name,) + s.axes, s.dtype, s.init,
+                  s.scale if s.scale is not None else fan_in_scale(s.shape))
         for s in leaves
     ]
     return jax.tree_util.tree_unflatten(treedef, stacked)

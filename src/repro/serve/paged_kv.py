@@ -20,11 +20,10 @@ of alloc/free churn), and two budgets bound the store:
   whole LRU entries are *evicted* (pages freed, the scheduler re-queues
   the request for a fresh prefill).
 
-On the CPU container every space is ``unpinned_host`` (see docs/DESIGN.md
-§2): ``place`` degrades to a no-op data move and residency is tracked
-logically — the claim structure (budget-bounded device high-water, spill
-counts, bit-parity across the spill) is what the tests and ``fig_traffic``
-assert, exactly as the rest of the repo treats placement on CPU.
+The CPU backend exposes ``device``, ``pinned_host`` and ``unpinned_host``
+memory kinds too, so spilled pages really change space there; what the
+tests and ``fig_traffic`` assert is the claim structure (budget-bounded
+device high-water, spill counts, bit-parity across the spill).
 """
 from __future__ import annotations
 
@@ -37,13 +36,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.pool import DeviceBufferPool
-from repro.core.umem import MemSpace, place, preferred_host_space
+from repro.core.umem import (MemSpace, device_operands, place,
+                             preferred_host_space, space_of)
 from repro.launch.serve import KV_PLACE_KEYS
 
 DEFAULT_PAGE_TOKENS = 8
 
 
 @functools.partial(jax.jit, donate_argnums=(1,))
+@device_operands
 def _copy_into(src, dst):
     """Donating full overwrite: the result owns ``dst``'s (pooled) storage
     and carries ``src``'s values — how jax 'reuses' an immutable buffer."""
@@ -190,6 +191,10 @@ class PagedKVCache:
         local-attention cache has S = window slots); the untouched tail is
         zeros by construction (init_cache) and is re-padded exactly at
         gather."""
+        if space_of(leaf) != MemSpace.DEVICE.kind:
+            # slicing is a computation, and the TPU computes only on device
+            # memory: an offloaded (host-placed) cache is fetched first
+            leaf = place(leaf, MemSpace.DEVICE)
         S = leaf.shape[axis]
         valid = min(max(int(true_len), 1), S)
         pt = self.page_tokens

@@ -134,11 +134,13 @@ class ServeEngine:
 
         raw_decode = self.regions.decode_step.impl_fn("ref")
 
-        @region("DECODE_SLOTS", ledger=self.ledger)
-        def decode_slots(tok, cache, pos, active):
+        @region("DECODE_SLOTS", ledger=self.ledger,
+                size_fn=self.regions.decode_step.size_fn)
+        def decode_slots(params, tok, cache, pos, active):
             # the DECODE_STEP body per slot: batch-1 decode, per-slot pos —
             # identical math to the solo path, batched over the slot axis
-            new_tok, new_cache = jax.vmap(raw_decode)(tok, cache, pos)
+            new_tok, new_cache = jax.vmap(
+                raw_decode, in_axes=(None, 0, 0, 0))(params, tok, cache, pos)
             new_tok = jnp.where(active[:, None], new_tok, tok)
             return new_tok, new_cache
 
@@ -180,7 +182,8 @@ class ServeEngine:
         self.ticks = 0
 
     def _tick_fn(self, run, tok, cache, pos, active):
-        tok, cache = run(self._decode_slots, tok, cache, pos, active)
+        tok, cache = run(self._decode_slots, self.regions.params, tok, cache,
+                         pos, active)
         cache = run(self.regions.kv_append, cache)
         return tok, cache
 

@@ -149,6 +149,60 @@ def solo_reference(cfg, mesh, params, requests: Sequence[Request],
     return out, wall_s
 
 
+#: tolerance of every engine token against the teacher-forced solo logits,
+#: in bf16 spacings at the reference's top logit (see
+#: :func:`assert_logit_parity`)
+LOGIT_TOL_ULPS = 8
+
+
+def assert_logit_parity(cfg, mesh, params, requests: Sequence[Request],
+                        oracle: Dict[int, List[int]], max_len: int, *,
+                        offload_kv: bool = False,
+                        q_chunk: int = 256) -> dict:
+    """Check every engine token against the solo jit path's logits.
+
+    The solo path is teacher-forced on each request's engine stream: it
+    prefills the prompt, then decodes the engine's own tokens, so at every
+    step both sides have the same history.  Every engine token must sit
+    within ``LOGIT_TOL_ULPS`` bf16 spacings of the reference's top logit.
+    Why a tolerance: the engine decodes a vmapped batch of slots and the
+    reference one request at a time, so the same bf16 math accumulates in
+    different orders, and an ulp of difference flips a near tie among a
+    large vocabulary's bf16 logits (seen at full width on a TPU v5e;
+    bitwise parity holds on the CPU backend, where the tests assert it
+    with :func:`assert_parity`).  Returns the tokens checked, the number
+    of requests whose stream left the free-running solo decode
+    (``oracle``) at such a tie, and the largest gap, in bf16 spacings."""
+    prefill, decode, make_cache = build_server(
+        cfg, mesh, 1, max_len, q_chunk=q_chunk, offload_kv=offload_kv)
+    eps = float(jnp.finfo(jnp.bfloat16).eps)
+    stats = {"tokens": 0, "diverged": 0, "gap_max": 0.0}
+    for r in requests:
+        solo = oracle[r.req_id]
+        if len(r.tokens) != len(solo):
+            raise AssertionError(f"request {r.req_id}: {len(r.tokens)} "
+                                 f"tokens, solo decode gave {len(solo)}")
+        stats["diverged"] += list(r.tokens) != list(solo)
+        logits, cache = prefill(params, batch_for_prompt(cfg, r.prompt),
+                                make_cache())
+        for i, tok in enumerate(r.tokens):
+            if i:
+                logits, cache = decode(
+                    params, jnp.asarray([r.tokens[i - 1]], jnp.int32), cache,
+                    jnp.int32(r.prompt_len + i - 1))
+            lg = np.asarray(logits[0, -1], np.float32)
+            top = float(lg.max())
+            gap = (top - float(lg[tok])) / (eps * max(abs(top), 1e-30))
+            stats["tokens"] += 1
+            stats["gap_max"] = max(stats["gap_max"], gap)
+            if gap > LOGIT_TOL_ULPS:
+                raise AssertionError(
+                    f"request {r.req_id}, token {i}: {tok} sits {gap:.1f} "
+                    f"bf16 spacings below the teacher-forced solo path's "
+                    f"top logit (tolerance {LOGIT_TOL_ULPS})")
+    return stats
+
+
 def assert_parity(requests: Sequence[Request],
                   oracle: Dict[int, List[int]]) -> None:
     """The bit-parity contract: every engine token sequence equals the

@@ -15,10 +15,11 @@ The four registered workloads mirror the ``fig_tune`` benchmark:
 * ``serve_decode`` — the serve DECODE_STEP+KV_APPEND program at the
   analysis-corpus smoke shape; ref ``discrete``.
 * ``train_step`` — the FWD_BWD+ADAMW_UPDATE step; ref ``discrete``.
-* ``cfd_sharded`` — the SIMPLE step decomposed over simulated APUs via
-  a ``repro.launch.scaling`` subprocess (the APU count must be in
-  XLA_FLAGS before jax imports); ref is the sequential 1-D slab
-  schedule (the PR-3 baseline).
+* ``cfd_sharded`` — the SIMPLE step decomposed over several devices via
+  ``repro.launch.scaling.run`` (in this process on real chips, in a
+  subprocess on forced CPU devices, whose count must be in XLA_FLAGS
+  before jax imports); ref is the sequential 1-D slab schedule (the PR-3
+  baseline).
 
 Contexts are built once per process (capture is the expensive part) and
 cached, the same trick as ``repro.analysis.programs``.
@@ -27,10 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 from pathlib import Path
@@ -40,6 +38,7 @@ import numpy as np
 
 from repro.core.ledger import Ledger
 from repro.core.regions import Executor, Placer, UnifiedPolicy
+from repro.launch import scaling
 from repro.tune.space import PolicyCandidate, cfd_size, serve_size, train_size
 
 #: serve/train smoke shapes (mirror repro.analysis.programs)
@@ -227,7 +226,8 @@ def _run_train(candidate: PolicyCandidate, steps: int,
 
 
 # ---------------------------------------------------------------------------
-# cfd_sharded (subprocess — the APU count must precede the jax import)
+# cfd_sharded (repro.launch.scaling.run: a subprocess on forced CPU
+# devices, where the APU count must precede the jax import)
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
@@ -247,23 +247,20 @@ def _run_sharded(candidate: PolicyCandidate, steps: int,
     apus = 1
     for s in mesh:
         apus *= s
+    argv = ["--apus", str(apus),
+            "--mesh", "x".join(str(s) for s in mesh),
+            "--steps", str(steps),
+            "--grid", ",".join(str(g) for g in SHARD_GRID),
+            "--policy", candidate.placement,
+            "--schedule", candidate.schedule,
+            "--halo-multiplier", str(candidate.halo_multiplier),
+            "--inner-max", str(SHARD_INNER)]
     with tempfile.TemporaryDirectory() as td:
-        out = Path(td) / "run.json"
-        cmd = [sys.executable, "-m", "repro.launch.scaling",
-               "--apus", str(apus),
-               "--mesh", "x".join(str(s) for s in mesh),
-               "--steps", str(steps),
-               "--grid", ",".join(str(g) for g in SHARD_GRID),
-               "--policy", candidate.placement,
-               "--schedule", candidate.schedule,
-               "--halo-multiplier", str(candidate.halo_multiplier),
-               "--inner-max", str(SHARD_INNER), "--out", str(out)]
-        r = subprocess.run(cmd, capture_output=True, text=True)
-        if r.returncode != 0:
-            raise RuntimeError(
-                f"sharded measurement failed for {candidate.label}:\n"
-                f"{r.stderr[-2000:]}")
-        rec = json.loads(out.read_text())
+        try:
+            rec = scaling.run(argv, Path(td) / "run.json")
+        except RuntimeError as e:
+            raise RuntimeError(f"sharded measurement failed for "
+                               f"{candidate.label}") from e
     if not rec["parity_ok"]:                 # DESIGN §2, asserted in-run too
         raise AssertionError(f"{candidate.label}: sharded replay lost "
                              f"parity: {rec['parity_max_abs_err']:.2e}")

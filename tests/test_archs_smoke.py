@@ -106,3 +106,14 @@ def test_param_counts_match_scale():
     for arch, n in expect.items():
         got = get_config(arch).n_params
         assert 0.5 * n < got < 2.0 * n, (arch, got, n)
+
+
+def test_stacked_layers_draw_at_the_per_layer_scale():
+    """A weight stacked for the scan over layers draws at 1/sqrt(fan_in)
+    of one layer: the stacking axis is not a fan-in (at 4 stacked cycles
+    it would make every scanned layer's weights sqrt(d/4) times too big)."""
+    from repro.models.params import ParamSpec, init_params, stack_specs
+    spec = {"w": ParamSpec((256, 64), ("embed", "mlp"), "float32")}
+    w = init_params(jax.random.PRNGKey(0), stack_specs(spec, 4))["w"]
+    assert w.shape == (4, 256, 64)
+    np.testing.assert_allclose(float(jnp.std(w)), 1 / 16, rtol=0.05)

@@ -123,3 +123,25 @@ class TestUmem:
         y = place(x, MemSpace.DEVICE)
         assert space_of(y) == "device"
         np.testing.assert_array_equal(np.asarray(y), 1.0)
+
+
+def test_compile_cache_dir_honours_env_else_fixed_path(monkeypatch,
+                                                         tmp_path):
+    """The entry points' cache helper leaves JAX's reading of
+    JAX_COMPILATION_CACHE_DIR alone when it is set, and otherwise points
+    the cache at the same ``<checkout>/.jax_cache`` on every call."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch import compilation as C
+    saved = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert C.configure_compilation() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == saved
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first, second = C.configure_compilation(), C.configure_compilation()
+        assert first == second == str(C.CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+        assert (C.CHECKOUT / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+        compilation_cache.reset_cache()

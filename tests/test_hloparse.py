@@ -28,7 +28,8 @@ fu = hloparse.analyze(jax.jit(unrolled).lower(x, x).compile().as_text()).flops
 assert abs(fs - fu) / fu < 0.01, (fs, fu)
 assert abs(fu - 10 * 2 * 256**3) / (10 * 2 * 256**3) < 0.01
 
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import make_smoke_mesh
+mesh = make_smoke_mesh((8,), ("model",))
 def sharded(x, w):
     def body(c, _):
         y = jax.lax.with_sharding_constraint(

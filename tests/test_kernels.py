@@ -84,3 +84,22 @@ class TestRwkv6Scan:
         ro, rs = R.rwkv6_scan(r, k, v, logw, u)
         np.testing.assert_allclose(np.asarray(co), np.asarray(ro),
                                    rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("op", ["fused_axpy", "stencil_spmv"])
+def test_interpret_choice_follows_lowering_platform(op):
+    """One jitted kernel call: lowered for a TPU it is a Mosaic custom
+    call, lowered for the CPU it runs in the Pallas interpreter. The choice
+    is made per lowering, not at import or from the default backend."""
+    import jax
+    from repro.kernels.fused_field import kernel as FF
+    from repro.kernels.stencil_spmv import kernel as SS
+    x = jnp.ones((8, 8, 8), jnp.float32)
+    fn, args = {"fused_axpy": (FF.fused_axpy, (2.0, x, x)),
+                "stencil_spmv": (SS.stencil_spmv,
+                                 (x, jnp.stack([x] * 6), x))}[op]
+    traced = jax.jit(fn).trace(*args)
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" in tpu
+    assert "tpu_custom_call" not in cpu

@@ -338,7 +338,8 @@ def test_sharded_scatter_respects_staging_budget():
     d = jnp.linspace(1.0, 2.0, int(np.prod(grid))).reshape(grid)
     x = jnp.full(grid, 0.3, jnp.float32)
     prog = capture(step, d, x, name="ov3d")
-    mesh = jax.make_mesh((1,), ("apu",), devices=jax.devices()[:1])
+    from repro.launch.mesh import make_apu_mesh
+    mesh = make_apu_mesh(1)
     ref = shard_program(prog, mesh, DiscretePolicy()).replay(d, x)
     budget = MemoryBudget(16384)         # chunk = 4 KiB < one 16 KiB field
     out = shard_program(prog, mesh,

@@ -6,9 +6,10 @@ CODE = r'''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_smoke_mesh
 from repro.runtime.pipeline import gpipe_apply, split_microbatches
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_smoke_mesh((4,), ("pod",))
 S, d = 4, 8
 ws = jnp.asarray(np.random.RandomState(1).randn(S, d, d) * 0.3, jnp.float32)
 def stage(w, x): return jnp.tanh(x @ w)

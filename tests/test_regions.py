@@ -389,3 +389,23 @@ def test_adaptive_executor_shares_ledger_with_staging_metrics():
     rep = ldg.coverage_report()
     assert rep["host_calls"] == 1 and rep["device_calls"] == 1
     assert "staging_fraction" in rep      # same report as staging metrics
+
+
+def test_host_routed_region_returns_on_cpu_device():
+    """A region routed to ``host`` moves its operands to the CPU device and
+    returns there; entering ``jax.default_device`` alone would leave
+    operands committed elsewhere (an accelerator, another memory space)
+    where they are, and the call with them."""
+    from repro.core.regions import host_device
+    led = Ledger("host_route")
+
+    @region("host_route", ledger=led)
+    def twice(x):
+        return 2.0 * x
+
+    x = jax.device_put(jnp.arange(8.0), jax.sharding.SingleDeviceSharding(
+        jax.devices()[0], memory_kind="pinned_host"))
+    y = Executor(HostPolicy(), led).run(twice, x)
+    assert y.devices() == {host_device()}
+    assert space_of(y) == host_device().default_memory().kind
+    np.testing.assert_array_equal(np.asarray(y), 2.0 * np.arange(8.0))

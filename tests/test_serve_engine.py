@@ -206,6 +206,7 @@ def test_engine_parity_offload_kv_placer(setup):
     eng, ex, kv = _engine(setup, policy=pol, ledger_name="offkv")
     run_traffic(eng, reqs)
     assert_parity(reqs, setup["oracle"])
+    assert SV.kv_spaces(eng.slot_cache) == {"pinned_host"}
 
 
 # ---------------------------------------------------------------------------
@@ -332,3 +333,28 @@ def test_decode_stream_sync_every_contract(setup, sync_every,
     # sync cadence is scheduling, not math
     ref = _stream_with_sync(setup, 0, [])
     assert toks == ref
+
+
+def test_logit_parity_accepts_engine_tokens_rejects_others(setup):
+    """The launcher's tolerance check: every engine token passes (they
+    equal the solo decode on this backend, so nothing diverges and no gap
+    opens), and a stream holding the reference's least likely token
+    fails."""
+    from repro.serve.scheduler import batch_for_prompt
+    from repro.serve.traffic import assert_logit_parity
+    cfg, mesh, params = setup["cfg"], setup["mesh"], setup["params"]
+    reqs = _traffic(cfg, setup["seed"])
+    eng, ex, kv = _engine(setup, ledger_name="logits")
+    run_traffic(eng, reqs)
+    stats = assert_logit_parity(cfg, mesh, params, reqs, setup["oracle"],
+                                MAX_LEN)
+    assert stats == {"tokens": sum(len(r.tokens) for r in reqs),
+                     "diverged": 0, "gap_max": 0.0}
+    prefill, _, make_cache = SV.build_server(cfg, mesh, 1, MAX_LEN)
+    r = reqs[0]
+    logits, _ = prefill(params, batch_for_prompt(cfg, r.prompt),
+                        make_cache())
+    r.tokens = [int(jnp.argmin(logits[0, -1]))] + list(r.tokens[1:])
+    with pytest.raises(AssertionError, match="bf16 spacings"):
+        assert_logit_parity(cfg, mesh, params, reqs, setup["oracle"],
+                            MAX_LEN)

@@ -34,8 +34,8 @@ from repro.train import step as S
 # ---------------------------------------------------------------------------
 
 def _recording_tree_place(monkeypatch):
-    """Swap serve's tree_place for a recorder (placement itself is a no-op
-    assertion target on CPU, where every space is unpinned_host)."""
+    """Swap serve's tree_place for a recorder: the test asserts which
+    leaves are placed, not where they land."""
     calls = []
 
     def rec(tree, space, device=None, min_bytes=0):

@@ -52,7 +52,7 @@ GRID = (8, 8, 8)
 
 
 def apu_mesh_1():
-    return jax.make_mesh((1,), ("apu",), devices=jax.devices()[:1])
+    return make_apu_mesh(1)
 
 
 def make_field_program(ledger=None):
@@ -159,7 +159,9 @@ def _exchanged_steps(chunks, n_steps, ghost):
     """The chunked ghost-zone model of the sharded replay: ONE exchange of
     ``ghost``-wide halos, then ``n_steps`` stencil applications on the
     extended chunks, keeping the interior.  Valid while n_steps <= ghost
-    (one layer of ghost validity is consumed per application)."""
+    (one layer of ghost validity is consumed per application).  Ghost
+    cells past the global boundary hold the zero-Dirichlet value through
+    every application, as the undecomposed boundary does."""
     assert n_steps <= ghost
     n = len(chunks)
     ext = []
@@ -171,6 +173,8 @@ def _exchanged_steps(chunks, n_steps, ghost):
         ext.append(np.concatenate([left, c, right]))
     for _ in range(n_steps):
         ext = [_stencil1d(e) for e in ext]
+        ext[0][:ghost] = 0.0
+        ext[-1][len(ext[-1]) - ghost:] = 0.0
     return [e[ghost:len(e) - ghost] for e in ext]
 
 
