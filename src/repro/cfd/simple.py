@@ -28,7 +28,7 @@ from repro.cfd.grid import Grid
 from repro.cfd.precond import rb_dilu_factor
 from repro.cfd.solvers import (make_solver_regions, pbicgstab_fused,
                                pbicgstab_regions)
-from repro.core.ledger import Ledger
+from repro.core.ledger import STEP, Ledger, span
 from repro.core.regions import Executor, UnifiedPolicy, region
 
 
@@ -153,47 +153,51 @@ class SimpleFoam:
     def time_step(self, st: SimpleState, executor=None) -> tuple:
         """One SIMPLE iteration.  ``executor`` overrides ``self.ex`` for this
         call only — program capture passes a recording executor here."""
-        cfg, ex = self.cfg, executor if executor is not None else self.ex
-        run = ex.run
-        # --- momentum predictor -------------------------------------
-        du, off, ru, dv, rv, dw, rw = run(self.assemble_momentum,
-                                          st.u, st.v, st.w, st.p)
-        rdiag_m = run(self.factor, du, off)
-        from repro.cfd.precond import RBDilu
-        Pm = RBDilu(rdiag_m, self.red)
-        Au = DiaMatrix(du, off)
-        res_u = pbicgstab_regions(ex, self.solver_regions, Au, ru, st.u, Pm,
-                                  tol=cfg.tol_u, max_iter=cfg.inner_max)
-        res_v = pbicgstab_regions(ex, self.solver_regions, DiaMatrix(dv, off),
-                                  rv, st.v, Pm, tol=cfg.tol_u,
-                                  max_iter=cfg.inner_max)
-        res_w = pbicgstab_regions(ex, self.solver_regions, DiaMatrix(dw, off),
-                                  rw, st.w, Pm, tol=cfg.tol_u,
-                                  max_iter=cfg.inner_max)
-        u_s, v_s, w_s = res_u.x, res_v.x, res_w.x
-        rAU = run(self.recip_diag, du)
-        # --- pressure corrector (solves for the correction p') -------
-        p = st.p
-        for _ in range(self.cfg.n_correctors):
-            dp, offp, rp = run(self.assemble_pressure, rAU, u_s, v_s, w_s)
-            rdiag_p = run(self.factor, dp, offp)
-            Pp = RBDilu(rdiag_p, self.red)
-            res_p = pbicgstab_regions(ex, self.solver_regions,
-                                      DiaMatrix(dp, offp), rp,
-                                      jnp.zeros(rp.shape, rp.dtype), Pp,
-                                      tol=cfg.tol_p, max_iter=cfg.inner_max)
-            p_corr = res_p.x
-            # --- momentum corrector ----------------------------------
-            gpx, gpy, gpz = run(self.grad_p, p_corr)
-            u_s, v_s, w_s = run(self.correct_u, u_s, v_s, w_s, rAU,
-                                gpx, gpy, gpz)
-            p = run(self.relax_p, p, p_corr)
-        new = SimpleState(u_s, v_s, w_s, p, st.step + 1)
-        metrics = {
-            "res_u": res_u.final_residual, "iters_u": res_u.iters,
-            "res_p": res_p.final_residual, "iters_p": res_p.iters,
-        }
-        return new, metrics
+        with span(STEP):
+            cfg, ex = self.cfg, executor if executor is not None else self.ex
+            run = ex.run
+            # --- momentum predictor ---------------------------------
+            du, off, ru, dv, rv, dw, rw = run(self.assemble_momentum,
+                                              st.u, st.v, st.w, st.p)
+            rdiag_m = run(self.factor, du, off)
+            from repro.cfd.precond import RBDilu
+            Pm = RBDilu(rdiag_m, self.red)
+            Au = DiaMatrix(du, off)
+            res_u = pbicgstab_regions(ex, self.solver_regions, Au, ru, st.u,
+                                      Pm, tol=cfg.tol_u,
+                                      max_iter=cfg.inner_max)
+            res_v = pbicgstab_regions(ex, self.solver_regions,
+                                      DiaMatrix(dv, off), rv, st.v, Pm,
+                                      tol=cfg.tol_u, max_iter=cfg.inner_max)
+            res_w = pbicgstab_regions(ex, self.solver_regions,
+                                      DiaMatrix(dw, off), rw, st.w, Pm,
+                                      tol=cfg.tol_u, max_iter=cfg.inner_max)
+            u_s, v_s, w_s = res_u.x, res_v.x, res_w.x
+            rAU = run(self.recip_diag, du)
+            # --- pressure corrector (solves for the correction p') ---
+            p = st.p
+            for _ in range(self.cfg.n_correctors):
+                dp, offp, rp = run(self.assemble_pressure, rAU, u_s, v_s,
+                                   w_s)
+                rdiag_p = run(self.factor, dp, offp)
+                Pp = RBDilu(rdiag_p, self.red)
+                res_p = pbicgstab_regions(ex, self.solver_regions,
+                                          DiaMatrix(dp, offp), rp,
+                                          jnp.zeros(rp.shape, rp.dtype), Pp,
+                                          tol=cfg.tol_p,
+                                          max_iter=cfg.inner_max)
+                p_corr = res_p.x
+                # --- momentum corrector ------------------------------
+                gpx, gpy, gpz = run(self.grad_p, p_corr)
+                u_s, v_s, w_s = run(self.correct_u, u_s, v_s, w_s, rAU,
+                                    gpx, gpy, gpz)
+                p = run(self.relax_p, p, p_corr)
+            new = SimpleState(u_s, v_s, w_s, p, st.step + 1)
+            metrics = {
+                "res_u": res_u.final_residual, "iters_u": res_u.iters,
+                "res_p": res_p.final_residual, "iters_p": res_p.iters,
+            }
+            return new, metrics
 
     def run_steps(self, st: SimpleState, n: int) -> tuple:
         """Returns (state, fom_seconds_per_step, metrics_last)."""
@@ -259,6 +263,7 @@ class SimpleFoam:
                     "mesh) instead")
         t0 = time.perf_counter()
         for _ in range(n):
-            u, v, w, p = prog.replay(executor, st.u, st.v, st.w, st.p)
+            with span(STEP):
+                u, v, w, p = prog.replay(executor, st.u, st.v, st.w, st.p)
             st = SimpleState(u, v, w, p, st.step + 1)
         return st, (time.perf_counter() - t0) / n

@@ -26,7 +26,7 @@ from repro.cfd.dia import (DiaMatrix, STENCIL_OFFSETS, amul_pallas,
 from repro.cfd.fields import fused_axpy_pallas, fused_axpbypz_pallas
 from repro.cfd.precond import (RBDilu, jacobi_apply, rb_dilu_apply,
                                rb_dilu_factor, rb_dilu_pallas)
-from repro.core.ledger import Ledger
+from repro.core.ledger import SOLVE, Ledger, readback, span
 from repro.core.regions import region
 
 SMALL = 1e-20
@@ -110,46 +110,54 @@ def make_solver_regions(ledger: Optional[Ledger] = None):
 def pbicgstab_regions(executor, regions, A: DiaMatrix, b, x0, P: RBDilu,
                       tol: float = 1e-6, rel_tol: float = 0.0,
                       max_iter: int = 500) -> SolveResult:
-    """OpenFOAM PBiCGStab, one executor.run per offloaded region."""
-    run = executor.run
-    x = x0
-    # r = b - 1.0*Ax through the saxpy region (identical math) so the whole
-    # residual dataflow is region-visible — program capture
-    # (repro.core.program) records real dependencies instead of freezing a
-    # host-computed array as a constant
-    r = run(regions.saxpy, 1.0, run(regions.amul, A.diag, A.off, x), b)
-    rA0 = r
-    norm = float(run(regions.summag, b)) + SMALL
-    res0 = float(run(regions.summag, r)) / norm
-    res = res0
-    rho_old = alpha = omega = 1.0
-    # zeros in device memory whatever space b was staged into
-    p = jnp.zeros(b.shape, b.dtype)
-    v = jnp.zeros(b.shape, b.dtype)
-    it = 0
-    while res > tol and (rel_tol <= 0 or res / max(res0, SMALL) > rel_tol) \
-            and it < max_iter:
-        rho = float(run(regions.dot, rA0, r))
-        if abs(rho) < SMALL:
-            break
-        beta = (rho / rho_old) * (alpha / max(omega, SMALL))
-        p = run(regions.update_p, r, beta, p, omega, v)
-        yA = run(regions.precond, P.rdiag, P.red, A.off, p)
-        v = run(regions.amul, A.diag, A.off, yA)
-        denom = float(run(regions.dot, rA0, v))
-        alpha = rho / (denom if abs(denom) > SMALL else SMALL)
-        s = run(regions.saxpy, alpha, v, r)
-        zA = run(regions.precond, P.rdiag, P.red, A.off, s)
-        t = run(regions.amul, A.diag, A.off, zA)
-        tt = float(run(regions.dot, t, t))
-        ts = float(run(regions.dot, t, s))
-        omega = ts / (tt if abs(tt) > SMALL else SMALL)
-        x = run(regions.update_x, x, alpha, yA, omega, zA)
-        r = run(regions.saxpy, omega, t, s)
-        rho_old = rho
-        res = float(run(regions.summag, r)) / norm
-        it += 1
-    return SolveResult(x, it, res0, res, res <= tol)
+    """OpenFOAM PBiCGStab, one executor.run per offloaded region.  Every
+    scalar the host reads back goes through ``readback``, counted on the
+    executor's ledger where it has one."""
+    with span(SOLVE):
+        run = executor.run
+        ledger = getattr(executor, "ledger", None)
+
+        def read(x) -> float:
+            return readback(x, ledger)
+
+        x = x0
+        # r = b - 1.0*Ax through the saxpy region (identical math) so the
+        # whole residual dataflow is region-visible — program capture
+        # (repro.core.program) records real dependencies instead of
+        # freezing a host-computed array as a constant
+        r = run(regions.saxpy, 1.0, run(regions.amul, A.diag, A.off, x), b)
+        rA0 = r
+        norm = read(run(regions.summag, b)) + SMALL
+        res0 = read(run(regions.summag, r)) / norm
+        res = res0
+        rho_old = alpha = omega = 1.0
+        # zeros in device memory whatever space b was staged into
+        p = jnp.zeros(b.shape, b.dtype)
+        v = jnp.zeros(b.shape, b.dtype)
+        it = 0
+        while res > tol and it < max_iter and (
+                rel_tol <= 0 or res / max(res0, SMALL) > rel_tol):
+            rho = read(run(regions.dot, rA0, r))
+            if abs(rho) < SMALL:
+                break
+            beta = (rho / rho_old) * (alpha / max(omega, SMALL))
+            p = run(regions.update_p, r, beta, p, omega, v)
+            yA = run(regions.precond, P.rdiag, P.red, A.off, p)
+            v = run(regions.amul, A.diag, A.off, yA)
+            denom = read(run(regions.dot, rA0, v))
+            alpha = rho / (denom if abs(denom) > SMALL else SMALL)
+            s = run(regions.saxpy, alpha, v, r)
+            zA = run(regions.precond, P.rdiag, P.red, A.off, s)
+            t = run(regions.amul, A.diag, A.off, zA)
+            tt = read(run(regions.dot, t, t))
+            ts = read(run(regions.dot, t, s))
+            omega = ts / (tt if abs(tt) > SMALL else SMALL)
+            x = run(regions.update_x, x, alpha, yA, omega, zA)
+            r = run(regions.saxpy, omega, t, s)
+            rho_old = rho
+            res = read(run(regions.summag, r)) / norm
+            it += 1
+        return SolveResult(x, it, res0, res, res <= tol)
 
 
 # ---------------------------------------------------------------------------

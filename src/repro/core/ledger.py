@@ -9,10 +9,55 @@ PETSc-style) vs Fig 4 (directive, near-total) comparison.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import time
 from typing import Dict, List, Optional
+
+import jax
+
+
+#: prefix of every host span the program opens (:func:`span`)
+SPAN_PREFIX = "repro:"
+#: span kinds: what the host is doing inside the span.  ``step`` is one
+#: SIMPLE iteration, ``solve`` one linear solve, ``region`` one region call
+#: (routing, placement, bookkeeping and what follows); inside a region
+#: call ``dispatch`` enqueues the compiled executable, ``sync`` waits for
+#: the device, ``place`` reshards operands or results, ``exchange``
+#: dispatches a halo exchange and ``exchange_wait`` waits for the worker
+#: thread that waits on one; ``readback`` copies a device scalar to the
+#: host.
+STEP = "step"
+SOLVE = "solve"
+REGION = "region"
+DISPATCH = "dispatch"
+SYNC = "sync"
+READBACK = "readback"
+EXCHANGE = "exchange"
+EXCHANGE_WAIT = "exchange_wait"
+PLACE = "place"
+
+
+def span(kind: str, name: Optional[str] = None, **ids):
+    """A host span ``repro:<kind>[:<name>]`` in the profiler's trace, on
+    the device trace's clock; ``ids`` become the event's stats (``op``, the
+    index of the op in a replay, shared by every span of that op on any
+    thread; ``impl``/``target`` on region spans, or set later with
+    ``set_metadata``).  Inert unless a profiler session runs.  ``#`` opens
+    the stats in a TraceMe name, so a uniquified region name (``dot#2``) is
+    written ``dot_2``."""
+    label = SPAN_PREFIX + kind
+    if name is not None:
+        label += ":" + name.replace("#", "_")
+    return jax.profiler.TraceAnnotation(label, **ids)
+
+
+def readback(x, ledger: Optional["Ledger"] = None) -> float:
+    """Copy a device scalar to the host as a float, inside a ``readback``
+    span, and count it on ``ledger`` (if given)."""
+    with span(READBACK):
+        value = float(x)
+    if ledger is not None:
+        ledger.readbacks += 1
+    return value
 
 
 def variant_cell(target: str, bucket: int) -> str:
@@ -107,6 +152,10 @@ class Ledger:
         # pools attached for byte-level accounting (paper C4): their live
         # PoolStats are snapshotted into every coverage_report()
         self._pools: Dict[str, object] = {}
+        # host reads of a device value (readback()), and block_until_ready
+        # calls on the region path: both per-run, like timings
+        self.readbacks = 0
+        self.syncs = 0
 
     def region(self, name: str, offloaded: bool = True) -> RegionRecord:
         if name not in self.regions:
@@ -201,6 +250,7 @@ class Ledger:
             #                             and cutoff persist like settings
         self.serve_counters.clear()     # per-run accounting, like timings;
         self.serve_gauges.clear()       # attached pools persist like settings
+        self.readbacks = self.syncs = 0
         # analysis_counters persist: verification is per capture, not per
         # run — resetting timings must not erase what the verifier found
 
@@ -236,6 +286,8 @@ class Ledger:
             self.serve_gauges[k] = max(self.serve_gauges.get(k, v), v)
         for k, v in other.analysis_counters.items():
             self.analysis_counters[k] = self.analysis_counters.get(k, 0) + v
+        self.readbacks += other.readbacks
+        self.syncs += other.syncs
 
     @classmethod
     def merged(cls, ledgers, name: str = "node") -> "Ledger":
@@ -254,6 +306,7 @@ class Ledger:
         self.serve_gauges.clear()
         self.analysis_counters.clear()
         self._pools.clear()
+        self.readbacks = self.syncs = 0
 
     # ------------------------------------------------------------------
     def coverage_report(self) -> dict:
@@ -352,6 +405,10 @@ class Ledger:
             "impl_counts": impl_counts,
             "calibrated_variants": calibrated,
             "variant_wins": variant_wins,
+            # host round trips: device scalars read back, and the syncs
+            # after region calls (one per call until syncs are dropped)
+            "readbacks": self.readbacks,
+            "syncs": self.syncs,
         }
 
     def table(self) -> List[dict]:
@@ -359,15 +416,6 @@ class Ledger:
 
 
 GLOBAL_LEDGER = Ledger()
-
-
-@contextlib.contextmanager
-def timed_region(ledger: Ledger, name: str, device: bool = True,
-                 offloaded: bool = True):
-    t0 = time.perf_counter()
-    yield
-    ledger.record(name, device=device, offloaded=offloaded,
-                  compute_s=time.perf_counter() - t0)
 
 
 def offload_region(name: Optional[str] = None, *, offloaded: bool = True,

@@ -56,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import umem
-from repro.core.ledger import GLOBAL_LEDGER, Ledger
+from repro.core.ledger import (DISPATCH, GLOBAL_LEDGER, REGION, SYNC, Ledger,
+                               span)
 from repro.core.pool import DeviceBufferPool, HostStagingPool
 from repro.core.umem import MemSpace, UnifiedArena
 
@@ -1051,43 +1052,50 @@ class Executor:
 
     def run(self, target_region, *args, **kwargs):
         r = as_region(target_region)
-        pol = self.policy
-        n = r.size_fn(args, kwargs)
-        tgt = pol.router.target(r, args, kwargs, size=n)
-        # resolve() here, not just in executable(): custom selectors may
-        # return unregistered names, and the ledger must record what RAN
-        impl = r.resolve(policy_selector(pol).select(r, tgt, args, kwargs,
-                                                     size=n))
-        args, kwargs = pol.placer.place_args(r, args, kwargs)
-        staging_s = 0.0
-        staging_b = 0
-        stage = pol.stager.stages and r.offloaded and tgt != "host"
-        staged_in = None
-        if stage:
-            (args, kwargs), s, b = pol.stager.stage_in(r, args, kwargs)
-            staged_in = (args, kwargs)
-            staging_s += s
-            staging_b += b
-        t0 = time.perf_counter()
-        # donation is disabled under staging policies: staged operands may
-        # alias pooled pages whose lifetime the stager manages; and a
-        # host-placed operand cannot back a result in device memory
-        donate = not pol.stager.stages and not _host_placed(
-            [args[i] for i in (r.donate_args or ()) if i < len(args)])
-        out = r.executable(tgt, impl, donate=donate)(*args, **kwargs)
-        jax.block_until_ready(out)
-        compute_s = time.perf_counter() - t0
-        if stage:
-            out, s, b = pol.stager.stage_out(r, out, staged_in)
-            staging_s += s
-            staging_b += b
-        out = pol.placer.place_result(r, out)
-        device = r.offloaded if tgt == "default" else (tgt == "device")
-        self.ledger.record(self._row_name(r), device=device,
-                           offloaded=r.offloaded,
-                           compute_s=compute_s, staging_s=staging_s,
-                           staging_bytes=staging_b, elems=n, impl=impl)
-        return out
+        with span(REGION, r.name) as call_span:
+            pol = self.policy
+            n = r.size_fn(args, kwargs)
+            tgt = pol.router.target(r, args, kwargs, size=n)
+            # resolve() here, not just in executable(): custom selectors
+            # may return unregistered names, and the ledger must record
+            # what RAN
+            impl = r.resolve(policy_selector(pol).select(r, tgt, args,
+                                                         kwargs, size=n))
+            call_span.set_metadata(impl=impl, target=tgt)
+            args, kwargs = pol.placer.place_args(r, args, kwargs)
+            staging_s = 0.0
+            staging_b = 0
+            stage = pol.stager.stages and r.offloaded and tgt != "host"
+            staged_in = None
+            if stage:
+                (args, kwargs), s, b = pol.stager.stage_in(r, args, kwargs)
+                staged_in = (args, kwargs)
+                staging_s += s
+                staging_b += b
+            t0 = time.perf_counter()
+            # donation is disabled under staging policies: staged operands
+            # may alias pooled pages whose lifetime the stager manages; and
+            # a host-placed operand cannot back a result in device memory
+            donate = not pol.stager.stages and not _host_placed(
+                [args[i] for i in (r.donate_args or ()) if i < len(args)])
+            exe = r.executable(tgt, impl, donate=donate)
+            with span(DISPATCH):
+                out = exe(*args, **kwargs)
+            with span(SYNC):
+                jax.block_until_ready(out)
+            self.ledger.syncs += 1
+            compute_s = time.perf_counter() - t0
+            if stage:
+                out, s, b = pol.stager.stage_out(r, out, staged_in)
+                staging_s += s
+                staging_b += b
+            out = pol.placer.place_result(r, out)
+            device = r.offloaded if tgt == "default" else (tgt == "device")
+            self.ledger.record(self._row_name(r), device=device,
+                               offloaded=r.offloaded,
+                               compute_s=compute_s, staging_s=staging_s,
+                               staging_bytes=staging_b, elems=n, impl=impl)
+            return out
 
     def report(self) -> dict:
         rep = self.ledger.coverage_report()

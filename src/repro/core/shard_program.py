@@ -113,7 +113,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.ledger import Ledger
+from repro.core.ledger import (DISPATCH, EXCHANGE, EXCHANGE_WAIT, PLACE,
+                               REGION, SYNC, Ledger, span)
 from repro.core.pool import DeviceBufferPool
 from repro.core.program import (Lit, Ref, RegionProgram, _is_array,
                                 _resolver, interval_overlap)
@@ -144,6 +145,7 @@ class _Exchange:
     nbytes: int                 # per-device bytes sent over the Fabric
     t0: float
     t1: float
+    op: int                     # index of the op it serves in the replay
 
     @property
     def seconds(self) -> float:
@@ -423,8 +425,8 @@ class ShardExecutor:
         return [(i, leaves[i]) for i in self._halo_leaf_indices(op)
                 if self._is_sharded(leaves[i])]
 
-    def _dispatch_exchange(self, r: Region, leaves: List[Tuple[int, Any]]
-                           ) -> _Exchange:
+    def _dispatch_exchange(self, r: Region, leaves: List[Tuple[int, Any]],
+                           op: int) -> _Exchange:
         """Dispatch the halo exchange over ``leaves`` — asynchronously, and
         ALWAYS from the main thread.  Everything this executor runs on the
         mesh contains collectives (the exchange's permutes, and the
@@ -438,39 +440,44 @@ class ShardExecutor:
         Per-device bytes: each APU sends ``w`` boundary slabs in each
         direction per decomposed dimension; a slab is the leaf's plane
         restricted to the APU's chunk of every *other* decomposed
-        dimension — the surface-to-volume term a 2-D mesh shrinks."""
+        dimension — the surface-to-volume term a 2-D mesh shrinks.
+
+        ``op`` is the index of the op the exchange serves: it tags the
+        ``exchange`` span here and the worker's ``sync`` span."""
         widths = self._stencil_widths(r) or {}
         k = self.halo_multiplier
         t0 = time.perf_counter()
         outs: Dict[int, Any] = {}
         nbytes = 0
-        for i, x in leaves:
-            asg = self._assignments(x.shape)
-            items = []
-            for d, ax, size in asg:
-                w = widths.get(ax, 0)
-                if w <= 0:
+        with span(EXCHANGE, op=op):
+            for i, x in leaves:
+                asg = self._assignments(x.shape)
+                items = []
+                for d, ax, size in asg:
+                    w = widths.get(ax, 0)
+                    if w <= 0:
+                        continue
+                    local = x.shape[d] // size
+                    items.append((d, min(k * w, local)))
+                    if size > 1:
+                        other = 1
+                        for d2, _, size2 in asg:
+                            if d2 != d:
+                                other *= size2
+                        plane = x.nbytes // x.shape[d] // other
+                        nbytes += 2 * min(k * w, local) * plane
+                if not items:
                     continue
-                local = x.shape[d] // size
-                items.append((d, min(k * w, local)))
-                if size > 1:
-                    other = 1
-                    for d2, _, size2 in asg:
-                        if d2 != d:
-                            other *= size2
-                    plane = x.nbytes // x.shape[d] // other
-                    nbytes += 2 * min(k * w, local) * plane
-            if not items:
-                continue
-            outs[i] = self._halo_region(r, tuple(items)).jitted(x)
-        return _Exchange(outs, nbytes, t0, t0)
+                outs[i] = self._halo_region(r, tuple(items)).jitted(x)
+        return _Exchange(outs, nbytes, t0, t0, op)
 
     def _finish_exchange(self, ex: _Exchange) -> _Exchange:
         """Wait for a dispatched exchange's transfers and close its wall
         interval (safe on the overlap worker: a pure wait, no dispatch).
         ``[t0, t1]`` is the in-flight window — the part of it intersecting
         compute spans is recorded as hidden (``overlap_s``)."""
-        jax.block_until_ready(list(ex.outs.values()))
+        with span(SYNC, op=ex.op):
+            jax.block_until_ready(list(ex.outs.values()))
         ex.t1 = time.perf_counter()
         return ex
 
@@ -545,7 +552,9 @@ class ShardExecutor:
         """Land one executed exchange on every per-device ledger (1/N
         shares): exchange seconds/bytes on the ``halo(<row>)`` row, plus
         the part of its wall interval that hid behind compute as
-        ``overlap_s`` (excluded from totals by the ledger)."""
+        ``overlap_s`` (excluded from totals by the ledger).  Its sync,
+        which may have run on the overlap worker, is counted here, on the
+        dispatching thread."""
         ov = min(interval_overlap(ex.t0, ex.t1, spans), ex.seconds)
         row = f"halo({self._row_name(r)})"
         nd = self.n_devices
@@ -553,6 +562,7 @@ class ShardExecutor:
             led.record(row, device=True, offloaded=True, compute_s=0.0,
                        exchange_s=ex.seconds / nd, exchange_bytes=ex.nbytes,
                        overlap_s=ov / nd)
+            led.syncs += 1
 
     def _submit_lookahead(self, tp, prog, plan, k, resolve_placed
                           ) -> Optional[Tuple[int, Future]]:
@@ -578,7 +588,7 @@ class ShardExecutor:
             if leaves:
                 # dispatch HERE (main thread — single collective enqueue
                 # order); the worker only waits out the transfer
-                ex = self._dispatch_exchange(op.region, leaves)
+                ex = self._dispatch_exchange(op.region, leaves, j)
                 return (j, tp.submit(self._finish_exchange, ex))
             return None             # due but nothing decomposed: skip
         return None
@@ -618,8 +628,9 @@ class ShardExecutor:
         in_leaves = list(prog._input_leaves(inputs))
         if not staging:
             # unified node model: inputs scatter once and stay decomposed
-            in_leaves = [self._place(x) if _is_array(x) else x
-                         for x in in_leaves]
+            with span(PLACE):
+                in_leaves = [self._place(x) if _is_array(x) else x
+                             for x in in_leaves]
         env: List[List[Any]] = []
         resolve = _resolver(env, in_leaves)
 
@@ -645,110 +656,134 @@ class ShardExecutor:
 
         for k, op in enumerate(prog.ops):
             r = op.region
-            raw = [resolve_placed(d) for d in op.leaves]
-            args, kwargs = jax.tree.unflatten(op.in_tree, raw)
-            n = r.size_fn(args, kwargs)
-            tgt = pol.router.target(r, args, kwargs, size=n)
-            if pending is not None and pending[0] == k:
-                ex_fut: Optional[Future] = pending[1]
-                pending = None
-            else:
-                ex_fut = None
-            if tgt == "host":
+            # every span of op k carries op=k, on the worker thread too
+            with span(REGION, r.name, op=k) as op_span:
+                with span(PLACE, op=k):
+                    raw = [resolve_placed(d) for d in op.leaves]
+                args, kwargs = jax.tree.unflatten(op.in_tree, raw)
+                n = r.size_fn(args, kwargs)
+                tgt = pol.router.target(r, args, kwargs, size=n)
+                if pending is not None and pending[0] == k:
+                    ex_fut: Optional[Future] = pending[1]
+                    pending = None
+                else:
+                    ex_fut = None
+                if tgt == "host":
+                    op_span.set_metadata(target=tgt)
+                    if ex_fut is not None:
+                        # prefetched exchange for a host-routed call: the
+                        # transfer happened; account it (with its overlap)
+                        with span(EXCHANGE_WAIT, op=k):
+                            ex = ex_fut.result()
+                        self._record_exchange(r, ex, spans)
+                    env.append(self._run_host(r, op, raw, n))
+                    continue
+                # variant selection happens here, per replayed call — the
+                # captured trace stores Regions, so the same program runs
+                # under any Selector at node scale too (XLA partitions
+                # whichever variant's executable is chosen; resolve():
+                # unknown -> ref)
+                impl = r.resolve(selector.select(r, tgt, args, kwargs,
+                                                 size=n))
+                op_span.set_metadata(impl=impl, target=tgt)
+                staging_s, staging_b = 0.0, 0
+                acquired: list = []
+                if staging and r.offloaded:
+                    raw, staging_s, staging_b, acquired = \
+                        self._stage_scatter(raw)
+                    args, kwargs = jax.tree.unflatten(op.in_tree, raw)
+                due = plan[k]
+                ex: Optional[_Exchange] = None
+                ex_leaves = self._exchange_leaves(op, raw) if due else []
+                split = self.split_stencil and bool(ex_leaves)
+                if due and ex_leaves and ex_fut is None and tp is None:
+                    # sequential schedule: exchange first, compute consumes
+                    # the exchanged operands (every exchange is exposed)
+                    ex = self._finish_exchange(
+                        self._dispatch_exchange(r, ex_leaves, k))
+                if ex is not None and not split:
+                    raw = list(raw)
+                    for i, y in ex.outs.items():
+                        raw[i] = y
+                    args, kwargs = jax.tree.unflatten(op.in_tree, raw)
+                exe = r.jitted_variant(impl, donate=False)
+                t0 = time.perf_counter()
+                # donate=False: sharded operands may be pool-staged or
+                # reused by the exchange bookkeeping — donation is a
+                # single-device executor optimization.  Under the
+                # overlapped schedules this dispatch is the INTERIOR
+                # compute: it consumes the un-exchanged (value-identical)
+                # operands, so it never waits on the exchange running
+                # behind it.
+                with span(DISPATCH, op=k):
+                    out = exe(*args, **kwargs)
+                if due and ex_leaves and ex is None and ex_fut is None:
+                    # this op's own exchange hides behind its own compute:
+                    # dispatched on THIS thread right after the compute
+                    # dispatch (ordered collectives), waited on by the
+                    # worker
+                    ex_fut = tp.submit(self._finish_exchange,
+                                       self._dispatch_exchange(r, ex_leaves,
+                                                               k))
+                # submit the NEXT due exchange before blocking on this
+                # compute — this ordering is the entire lookahead overlap
+                # (operands staged per-call can't be prefetched across ops)
+                if tp is not None and pending is None and not staging:
+                    pending = self._submit_lookahead(tp, prog, plan, k,
+                                                     resolve_placed)
+                with span(SYNC, op=k):
+                    jax.block_until_ready(out)
+                t1 = time.perf_counter()
+                note_span(t0, t1)
                 if ex_fut is not None:
-                    # prefetched exchange for a host-routed call: the
-                    # transfer happened; account it (with its overlap)
-                    self._record_exchange(r, ex_fut.result(), spans)
-                env.append(self._run_host(r, op, raw, n))
-                continue
-            # variant selection happens here, per replayed call — the
-            # captured trace stores Regions, so the same program runs under
-            # any Selector at node scale too (XLA partitions whichever
-            # variant's executable is chosen; resolve(): unknown -> ref)
-            impl = r.resolve(selector.select(r, tgt, args, kwargs, size=n))
-            staging_s, staging_b = 0.0, 0
-            acquired: list = []
-            if staging and r.offloaded:
-                raw, staging_s, staging_b, acquired = \
-                    self._stage_scatter(raw)
-                args, kwargs = jax.tree.unflatten(op.in_tree, raw)
-            due = plan[k]
-            ex: Optional[_Exchange] = None
-            ex_leaves = self._exchange_leaves(op, raw) if due else []
-            split = self.split_stencil and bool(ex_leaves)
-            if due and ex_leaves and ex_fut is None and tp is None:
-                # sequential schedule: exchange first, compute consumes
-                # the exchanged operands (every exchange is exposed)
-                ex = self._finish_exchange(
-                    self._dispatch_exchange(r, ex_leaves))
-            if ex is not None and not split:
-                raw = list(raw)
-                for i, y in ex.outs.items():
-                    raw[i] = y
-                args, kwargs = jax.tree.unflatten(op.in_tree, raw)
-            t0 = time.perf_counter()
-            # donate=False: sharded operands may be pool-staged or reused
-            # by the exchange bookkeeping — donation is a single-device
-            # executor optimization.  Under the overlapped schedules this
-            # dispatch is the INTERIOR compute: it consumes the
-            # un-exchanged (value-identical) operands, so it never waits
-            # on the exchange running behind it.
-            out = r.jitted_variant(impl, donate=False)(*args, **kwargs)
-            if due and ex_leaves and ex is None and ex_fut is None:
-                # this op's own exchange hides behind its own compute:
-                # dispatched on THIS thread right after the compute
-                # dispatch (ordered collectives), waited on by the worker
-                ex_fut = tp.submit(self._finish_exchange,
-                                   self._dispatch_exchange(r, ex_leaves))
-            # submit the NEXT due exchange before blocking on this
-            # compute — this ordering is the entire lookahead overlap
-            # (operands staged per-call can't be prefetched across ops)
-            if tp is not None and pending is None and not staging:
-                pending = self._submit_lookahead(tp, prog, plan, k,
-                                                 resolve_placed)
-            jax.block_until_ready(out)
-            t1 = time.perf_counter()
-            note_span(t0, t1)
-            if ex_fut is not None:
-                ex = ex_fut.result()
-            if split and ex is not None:
-                # causal boundary pass: recompute from exchanged operands,
-                # blend the ghost-adjacent band over the interior result
-                xraw = list(raw)
-                for i, y in ex.outs.items():
-                    xraw[i] = y
-                xargs, xkwargs = jax.tree.unflatten(op.in_tree, xraw)
-                bregion = self._boundary_region(r)
-                tb0 = time.perf_counter()
-                out = bregion.jitted(out, *xargs, **xkwargs)
-                jax.block_until_ready(out)
-                tb1 = time.perf_counter()
-                note_span(tb0, tb1)
+                    with span(EXCHANGE_WAIT, op=k):
+                        ex = ex_fut.result()
+                if split and ex is not None:
+                    # causal boundary pass: recompute from exchanged
+                    # operands, blend the ghost-adjacent band over the
+                    # interior result
+                    xraw = list(raw)
+                    for i, y in ex.outs.items():
+                        xraw[i] = y
+                    xargs, xkwargs = jax.tree.unflatten(op.in_tree, xraw)
+                    bregion = self._boundary_region(r)
+                    tb0 = time.perf_counter()
+                    with span(DISPATCH, op=k):
+                        out = bregion.jitted(out, *xargs, **xkwargs)
+                    with span(SYNC, op=k):
+                        jax.block_until_ready(out)
+                    tb1 = time.perf_counter()
+                    note_span(tb0, tb1)
+                    for led in self.ledgers:
+                        led.record(f"boundary({self._row_name(r)})",
+                                   device=True, offloaded=True,
+                                   compute_s=(tb1 - tb0) / nd)
+                        led.syncs += 1
+                if staging and r.offloaded:
+                    out, s, b = stager.stage_out(r, out, None)
+                    staging_s += s
+                    staging_b += b
+                    for buf in acquired:          # staged operands are dead
+                        self._device_pool.release(buf)
+                else:
+                    with span(PLACE, op=k):
+                        out = jax.tree.map(
+                            lambda x: self._place(x) if _is_array(x) else x,
+                            out)
+                row = self._row_name(r)
                 for led in self.ledgers:
-                    led.record(f"boundary({self._row_name(r)})",
-                               device=True, offloaded=True,
-                               compute_s=(tb1 - tb0) / nd)
-            if staging and r.offloaded:
-                out, s, b = stager.stage_out(r, out, None)
-                staging_s += s
-                staging_b += b
-                for buf in acquired:          # staged operands are dead
-                    self._device_pool.release(buf)
-            else:
-                out = jax.tree.map(
-                    lambda x: self._place(x) if _is_array(x) else x, out)
-            row = self._row_name(r)
-            for led in self.ledgers:
-                led.record(row, device=True, offloaded=r.offloaded,
-                           compute_s=(t1 - t0) / nd,
-                           staging_s=staging_s / nd,
-                           staging_bytes=staging_b // nd,
-                           elems=n // nd, impl=impl)
-            if ex is not None:
-                self._record_exchange(r, ex, spans)
-            env.append(jax.tree.leaves(out))
+                    led.record(row, device=True, offloaded=r.offloaded,
+                               compute_s=(t1 - t0) / nd,
+                               staging_s=staging_s / nd,
+                               staging_bytes=staging_b // nd,
+                               elems=n // nd, impl=impl)
+                    led.syncs += 1
+                if ex is not None:
+                    self._record_exchange(r, ex, spans)
+                env.append(jax.tree.leaves(out))
         if pending is not None:       # trailing prefetch past a host turn
-            pending[1].result()
+            with span(EXCHANGE_WAIT, op=pending[0]):
+                pending[1].result()
         return jax.tree.unflatten(prog.out_tree,
                                   [resolve(d) for d in prog.out_leaves])
 
@@ -762,6 +797,7 @@ class ShardExecutor:
         t0 = time.perf_counter()
         out = r.executable("host", impl, donate=False)(*args, **kwargs)
         jax.block_until_ready(out)
+        self.host_ledger.syncs += 1
         self.host_ledger.record(self._row_name(r), device=False,
                                 offloaded=r.offloaded,
                                 compute_s=time.perf_counter() - t0, elems=n,
