@@ -71,11 +71,13 @@ def fig6_migration(steps: int = 2, grid=(16, 16, 16)):
     """Paper Fig 6: fraction of step time in staging (page migration)."""
     from repro.cfd.grid import Grid
     from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
-    from repro.core.regions import DiscretePolicy, Executor, UnifiedPolicy
+    from repro.core.regions import (DiscretePolicy, SyncedExecutor,
+                                    UnifiedPolicy)
     cfg = SimpleConfig(grid=Grid(grid), nu=0.1, inner_max=15)
     for name, cls in (("discrete", DiscretePolicy),
                       ("unified", UnifiedPolicy)):
-        app = SimpleFoam(cfg, executor=Executor(cls()))
+        # every call waited for: the fraction's compute is device time
+        app = SimpleFoam(cfg, executor=SyncedExecutor(cls()))
         st = init_state(cfg)
         st, _, _ = app.run_steps(st, 1)
         app.ledger.reset_timings()
@@ -98,13 +100,14 @@ def fig6b_overlap(steps: int = 2, grid=(16, 16, 16)):
     from repro.cfd.grid import Grid
     from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
     from repro.core.program import AsyncExecutor
-    from repro.core.regions import DiscretePolicy, Executor
+    from repro.core.regions import DiscretePolicy, SyncedExecutor
     cfg = SimpleConfig(grid=Grid(grid), nu=0.1, inner_max=15)
     app = SimpleFoam(cfg)
     st = init_state(cfg)
     st, _, _ = app.run_steps(st, 1)              # develop flow + warm caches
     prog = app.capture_step(st)
-    sync = Executor(DiscretePolicy())
+    # waits for every op, as the async lookahead does
+    sync = SyncedExecutor(DiscretePolicy())
     asyn = AsyncExecutor(DiscretePolicy())
     app.replay_steps(prog, st, 1, sync)          # warm per-target caches
     app.replay_steps(prog, st, 1, asyn)
@@ -329,9 +332,12 @@ def fig4_coverage(grid=(12, 12, 12)):
     on host, solver offloaded) vs full directive mode."""
     from repro.cfd.grid import Grid
     from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
+    from repro.core.regions import SyncedExecutor, UnifiedPolicy
     cfg = SimpleConfig(grid=Grid(grid), nu=0.1, inner_max=15)
     for name, host_asm in (("petsc_mode", True), ("directive_mode", False)):
-        app = SimpleFoam(cfg, assemble_on_host=host_asm)
+        # every call waited for: coverage is a share of device time
+        app = SimpleFoam(cfg, executor=SyncedExecutor(UnifiedPolicy()),
+                         assemble_on_host=host_asm)
         st = init_state(cfg)
         st, _, _ = app.run_steps(st, 1)
         app.ledger.reset_timings()
@@ -361,7 +367,7 @@ def fig_serve(batch: int = 2, prompt_len: int = 12, gen: int = 8,
     from repro.configs.reduced import reduced as make_reduced
     from repro.configs.registry import get_config
     from repro.core.ledger import Ledger
-    from repro.core.regions import Executor
+    from repro.core.regions import Executor, SyncedExecutor
     from repro.launch import serve as SV
     from repro.launch.mesh import make_smoke_mesh
     from repro.launch.policy import lm_policy
@@ -421,7 +427,8 @@ def fig_serve(batch: int = 2, prompt_len: int = 12, gen: int = 8,
                                          placer=SV.offload_kv_cache())),
     )
     for name, make_pol in policies:
-        ex = Executor(make_pol(), Ledger(f"serve_{name}"))
+        # every call waited for: the time and fractions are device time
+        ex = SyncedExecutor(make_pol(), Ledger(f"serve_{name}"))
         tok, cache = prefill_prog.replay(ex, batch_in,
                                          T.init_cache(cfg, batch, max_len))
         decode_prog.replay(ex, tok, cache)          # warm per-target caches

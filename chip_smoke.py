@@ -79,7 +79,7 @@ def cfd_phase() -> None:
     from repro.cfd.grid import Grid
     from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
     from repro.core.regions import (DiscretePolicy, Executor, StaticSelector,
-                                    UnifiedPolicy)
+                                    SyncedExecutor, UnifiedPolicy)
     from repro.core.umem import space_of
 
     cfg = SimpleConfig(grid=Grid((CFD_GRID,) * 3), nu=0.1,
@@ -96,7 +96,9 @@ def cfd_phase() -> None:
             ("ref", UnifiedPolicy(selector=StaticSelector("ref"))),
             ("pallas", UnifiedPolicy(selector=StaticSelector("pallas"))),
             ("discrete", DiscretePolicy())):
-        ex = Executor(policy)
+        # the discrete replay reports a staging fraction: wait for every
+        # call there, so its denominator is device-inclusive
+        ex = (SyncedExecutor if name == "discrete" else Executor)(policy)
         app.replay_steps(prog, st, 1, ex)            # compiles
         ex.ledger.reset_timings()
         out, sps = app.replay_steps(prog, st, 2, ex)

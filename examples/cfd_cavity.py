@@ -1,7 +1,8 @@
 """The paper's case study: simpleFoam on a lid-driven cavity, executed by
 all three memory models (host / discrete-managed / unified) plus the
 beyond-paper adaptive policy, with the coverage + migration report
-(paper Figs 4-6).
+(paper Figs 4-6).  A SyncedExecutor waits for every region call, so the
+staging fraction is a share of device-inclusive seconds.
 
     PYTHONPATH=src python examples/cfd_cavity.py [--grid 20]
 """
@@ -9,8 +10,8 @@ import argparse
 
 from repro.cfd.grid import Grid
 from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
-from repro.core.regions import (AdaptivePolicy, DiscretePolicy, Executor,
-                                HostPolicy, UnifiedPolicy)
+from repro.core.regions import (AdaptivePolicy, DiscretePolicy, HostPolicy,
+                                SyncedExecutor, UnifiedPolicy)
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
@@ -23,7 +24,7 @@ if __name__ == "__main__":
                 ("unified", UnifiedPolicy()),
                 ("adaptive", AdaptivePolicy(cutoff=1024)))
     for name, policy in policies:
-        app = SimpleFoam(cfg, executor=Executor(policy))
+        app = SimpleFoam(cfg, executor=SyncedExecutor(policy))
         st = init_state(cfg)
         st, _, _ = app.run_steps(st, 1)          # warm compile caches
         app.ledger.reset_timings()

@@ -73,6 +73,12 @@ class RegionRecord:
     calls: int = 0
     device_calls: int = 0
     host_calls: int = 0
+    #: host-clock seconds of the calls.  A call the executor waited on
+    #: (a staged call, a replay's op) counts until the device finished
+    #: it; an unstaged ``Executor.run`` call does not wait, and counts
+    #: only the host's routing, placement and dispatch.  A
+    #: ``SyncedExecutor`` waits for every call; device seconds per region
+    #: otherwise come from a device trace.
     compute_s: float = 0.0
     device_compute_s: float = 0.0       # compute split by routing side — a
     host_compute_s: float = 0.0         # region may mix under AdaptivePolicy
@@ -152,8 +158,9 @@ class Ledger:
         # pools attached for byte-level accounting (paper C4): their live
         # PoolStats are snapshotted into every coverage_report()
         self._pools: Dict[str, object] = {}
-        # host reads of a device value (readback()), and block_until_ready
-        # calls on the region path: both per-run, like timings
+        # host reads of a device value (readback()), and the
+        # block_until_ready calls that happen on the region path (an
+        # Executor waits only for a staged call): both per-run, like timings
         self.readbacks = 0
         self.syncs = 0
 
@@ -405,8 +412,11 @@ class Ledger:
             "impl_counts": impl_counts,
             "calibrated_variants": calibrated,
             "variant_wins": variant_wins,
-            # host round trips: device scalars read back, and the syncs
-            # after region calls (one per call until syncs are dropped)
+            # host round trips: device scalars read back, and the region
+            # calls waited for.  A call not waited for booked only the
+            # host's seconds into compute_s, so with syncs < calls the
+            # compute, total and fraction fields above are host time in
+            # part; a SyncedExecutor waits for every call
             "readbacks": self.readbacks,
             "syncs": self.syncs,
         }

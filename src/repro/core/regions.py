@@ -1015,7 +1015,20 @@ class Executor:
     non-array outputs unchanged), regardless of policy.  The discrete policy
     stages results into host-space arrays — it does not leak numpy, which
     the old DiscreteExecutor did, silently changing downstream types per
-    mode."""
+    mode.
+
+    Sync contract: ``run`` waits for the device only when the host itself
+    reads the call's output inside the call, i.e. when the policy's stager
+    stages it.  Any other call returns as soon as it is dispatched, so the
+    device runs ahead of the host until the first host read
+    (``ledger.readback``, ``np.asarray``, the caller's own
+    ``block_until_ready``).  ``ledger.syncs`` counts the waits that
+    happen; an unstaged call's ``compute_s`` is the host's seconds in the
+    call (routing, placement, dispatch), not the device's.  Measure
+    per-region device-inclusive seconds with :class:`SyncedExecutor`."""
+
+    #: wait for every call, staged or not (:class:`SyncedExecutor`)
+    waits_for_every_call = False
 
     def __init__(self, policy: ExecutionPolicy, ledger: Optional[Ledger] = None):
         self.policy = policy
@@ -1081,9 +1094,12 @@ class Executor:
             exe = r.executable(tgt, impl, donate=donate)
             with span(DISPATCH):
                 out = exe(*args, **kwargs)
-            with span(SYNC):
-                jax.block_until_ready(out)
-            self.ledger.syncs += 1
+            if stage or self.waits_for_every_call:
+                # the stager reads a staged result on the host: wait for
+                # it here, so the copy's seconds stay out of compute_s
+                with span(SYNC):
+                    jax.block_until_ready(out)
+                self.ledger.syncs += 1
             compute_s = time.perf_counter() - t0
             if stage:
                 out, s, b = pol.stager.stage_out(r, out, staged_in)
@@ -1101,6 +1117,17 @@ class Executor:
         rep = self.ledger.coverage_report()
         rep["mode"] = self.mode
         return rep
+
+
+class SyncedExecutor(Executor):
+    """The measurement path: an :class:`Executor` that waits for every
+    call before it takes the call's ``compute_s``, so the Ledger's
+    compute seconds per region are those of finished device work (and a
+    staged call's copies stay in ``staging_s``).  The tuner's calibration
+    and the paper-figure reports (coverage, staging fraction) run under
+    it; the hot path does not, since the waits are what it saves."""
+
+    waits_for_every_call = True
 
 
 POLICIES = {

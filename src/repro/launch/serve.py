@@ -468,7 +468,9 @@ def main(argv=None):
                          "error-severity findings abort (repro.analysis, "
                          "docs/ANALYSIS.md)")
     ap.add_argument("--report", action="store_true",
-                    help="print the run's coverage_report() as JSON")
+                    help="print the run's coverage_report() as JSON (the "
+                         "compute seconds of calls not waited for, all "
+                         "but its 'syncs', are host dispatch time)")
     ap.add_argument("--sync-every", type=int, default=0, metavar="K",
                     help="jit streaming path: block_until_ready once per K "
                          "tokens; K <= 0 = never sync mid-stream, one "

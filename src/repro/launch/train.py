@@ -61,7 +61,10 @@ def build_trainer(cfg: ModelConfig, mesh, *, lr=3e-4, offload_optimizer=False,
     through ``ex`` — call it again after a restore to re-capture (the
     regions, and therefore the Ledger rows, are reused).
     ``ex`` is the Executor every step runs under; ``ex.report()`` is the
-    canonical coverage report for the run.
+    canonical coverage report for the run.  The Executor waits only for
+    staged calls, so the report's compute seconds (and the fractions
+    built on them) of the other calls are the host's dispatch time: its
+    ``syncs`` says how many calls were waited for.
 
     Memory note: the pre-regions trainer jitted the whole step with
     ``donate_argnums=(0,)``, updating params/moments in place.  Region
@@ -154,7 +157,9 @@ def main(argv=None):
                          "policy at capture; error-severity findings "
                          "abort (repro.analysis, docs/ANALYSIS.md)")
     ap.add_argument("--report", action="store_true",
-                    help="print the run's coverage_report() as JSON")
+                    help="print the run's coverage_report() as JSON (the "
+                         "compute seconds of calls not waited for, all "
+                         "but its 'syncs', are host dispatch time)")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")

@@ -248,7 +248,8 @@ def tune(workload: Workload, *, trials: int = 3, steps: Optional[int] = None,
     steps = steps or workload.steps
     if measure is None:
         def measure(w, c, s):
-            return w.run(c, s, winners=winners)
+            # only the reference's region seconds calibrate the residuals
+            return w.run(c, s, winners=winners, calibrate=c == w.ref)
     model = model_costs(workload.build_program())
 
     ref_res: Optional[RunResult] = None

@@ -3,8 +3,9 @@
 A :class:`Workload` gives the tuner everything it needs for one search:
 the captured :class:`~repro.core.program.RegionProgram` (cost-model
 input), a ``run(candidate, steps)`` measurement that replays it under
-the candidate's policy and returns parity leaves + a FOM + per-region
-measured seconds (residual calibration), the hand-assembled reference
+the candidate's policy and returns parity leaves + a FOM taken the way
+the hot path runs + (with ``calibrate=True``) per-region seconds taken
+on the synced path (residual calibration), the hand-assembled reference
 candidate the winner must beat, and the workload-shape ``size`` that
 keys the profile bucket.
 
@@ -34,10 +35,12 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.ledger import Ledger
-from repro.core.regions import Executor, Placer, UnifiedPolicy
+from repro.core.regions import (Executor, Placer, SyncedExecutor,
+                                UnifiedPolicy)
 from repro.launch import scaling
 from repro.tune.space import PolicyCandidate, cfd_size, serve_size, train_size
 
@@ -76,7 +79,8 @@ class Workload:
     size: int                        # bucket key (see space.*_size)
     memory: Any                      # MemoryPolicy for cutoff defaults
     build_program: Callable[[], Any]
-    run: Callable[..., RunResult]    # (candidate, steps, winners=) -> RunResult
+    run: Callable[..., RunResult]    # (candidate, steps, winners=,
+    #                                  calibrate=) -> RunResult
     ref: PolicyCandidate
     steps: int = 2                   # default measured replays
     meta: dict = dataclasses.field(default_factory=dict)
@@ -92,8 +96,20 @@ def _executor(candidate: PolicyCandidate, memory, winners, name: str):
     return cls(pol, Ledger(name))
 
 
-def _region_seconds(ledger: Ledger) -> Dict[str, float]:
-    return {name: row.compute_s for name, row in ledger.regions.items()
+def _region_seconds(ex, replay: Callable[[Any], Any],
+                    calibrate: bool) -> Dict[str, float]:
+    """Per-region compute seconds of ``replay(executor)`` under ``ex``'s
+    policy on the synced path, or ``{}`` when the run does not calibrate.
+
+    ``Executor.run`` does not wait for an unstaged call, so the hot
+    path's Ledger holds host seconds; the calibration replays through a
+    :class:`SyncedExecutor`, whose compute seconds are those of finished
+    calls and leave a staged call's copies in ``staging_s``."""
+    if not calibrate:
+        return {}
+    synced = SyncedExecutor(ex.policy, Ledger(f"{ex.ledger.name}_synced"))
+    replay(synced)
+    return {name: row.compute_s for name, row in synced.ledger.regions.items()
             if row.compute_s > 0}
 
 
@@ -112,15 +128,17 @@ def _cfd_ctx():
     return app, st, app.capture_step(st)
 
 
-def _run_cfd(candidate: PolicyCandidate, steps: int,
-             winners=None) -> RunResult:
+def _run_cfd(candidate: PolicyCandidate, steps: int, winners=None,
+             calibrate: bool = False) -> RunResult:
     app, st, prog = _cfd_ctx()
     ex = _executor(candidate, None, winners, f"tune_cfd_{candidate.label}")
     app.replay_steps(prog, st, 1, ex)        # warm per-target compiles
+    region_s = _region_seconds(
+        ex, lambda e: app.replay_steps(prog, st, steps, e), calibrate)
     ex.ledger.reset_timings()
     s, fom = app.replay_steps(prog, st, steps, ex)
     leaves = [np.asarray(f) for f in (s.u, s.v, s.w, s.p)]
-    return RunResult(leaves, fom, _region_seconds(ex.ledger), replays=steps)
+    return RunResult(leaves, fom, region_s, replays=steps)
 
 
 # ---------------------------------------------------------------------------
@@ -129,7 +147,6 @@ def _run_cfd(candidate: PolicyCandidate, steps: int,
 
 @functools.lru_cache(maxsize=None)
 def _serve_ctx():
-    import jax
     import jax.numpy as jnp
 
     from repro.configs.reduced import reduced as make_reduced
@@ -156,8 +173,8 @@ def _serve_ctx():
     return cfg, batch_in, prefill_prog, decode_prog
 
 
-def _run_serve(candidate: PolicyCandidate, steps: int,
-               winners=None) -> RunResult:
+def _run_serve(candidate: PolicyCandidate, steps: int, winners=None,
+               calibrate: bool = False) -> RunResult:
     import jax.numpy as jnp
 
     from repro.models import transformer as T
@@ -168,13 +185,19 @@ def _run_serve(candidate: PolicyCandidate, steps: int,
     ex = _executor(candidate, cfg.memory, winners,
                    f"tune_serve_{candidate.label}")
     decode_prog.replay(ex, tok, cache)       # warm per-target compiles
+
+    def decode(e):
+        for _ in range(steps):
+            toks = decode_prog.replay(e, tok, cache)
+        return jax.block_until_ready(toks)
+
+    region_s = _region_seconds(ex, decode, calibrate)
     ex.ledger.reset_timings()
     t0 = time.perf_counter()
-    for _ in range(steps):
-        toks = decode_prog.replay(ex, tok, cache)
+    toks = decode(ex)
     fom = (time.perf_counter() - t0) / (steps * max(GEN - 1, 1))
     leaves = [np.asarray(jnp.stack(toks, axis=1))]
-    return RunResult(leaves, fom, _region_seconds(ex.ledger), replays=steps)
+    return RunResult(leaves, fom, region_s, replays=steps)
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +209,6 @@ TRAIN_BATCH, TRAIN_SEQ = 2, 16
 
 @functools.lru_cache(maxsize=None)
 def _train_ctx():
-    import jax
     import jax.numpy as jnp
 
     from repro.configs.reduced import reduced as make_reduced
@@ -207,22 +229,27 @@ def _train_ctx():
     return cfg, (params, opt), batch, prog
 
 
-def _run_train(candidate: PolicyCandidate, steps: int,
-               winners=None) -> RunResult:
-    import jax
+def _run_train(candidate: PolicyCandidate, steps: int, winners=None,
+               calibrate: bool = False) -> RunResult:
     cfg, state0, batch, prog = _train_ctx()
     ex = _executor(candidate, cfg.memory, winners,
                    f"tune_train_{candidate.label}")
     prog.replay(ex, state0, batch)           # warm per-target compiles
+
+    def train(e):
+        state, metrics = state0, {}
+        for _ in range(steps):
+            state, metrics = prog.replay(e, state, batch)
+        return jax.block_until_ready((state, metrics))
+
+    region_s = _region_seconds(ex, train, calibrate)
     ex.ledger.reset_timings()
-    state, metrics = state0, {}
     t0 = time.perf_counter()
-    for _ in range(steps):
-        state, metrics = prog.replay(ex, state, batch)
+    state, metrics = train(ex)
     fom = (time.perf_counter() - t0) / steps
     leaves = [np.asarray(metrics["loss"]), np.asarray(metrics["grad_norm"])]
     leaves += [np.asarray(x) for x in jax.tree.leaves(state)[:2]]
-    return RunResult(leaves, fom, _region_seconds(ex.ledger), replays=steps)
+    return RunResult(leaves, fom, region_s, replays=steps)
 
 
 # ---------------------------------------------------------------------------
@@ -241,8 +268,8 @@ def _sharded_prog():
     return app.capture_step(st)
 
 
-def _run_sharded(candidate: PolicyCandidate, steps: int,
-                 winners=None) -> RunResult:
+def _run_sharded(candidate: PolicyCandidate, steps: int, winners=None,
+                 calibrate: bool = False) -> RunResult:
     mesh = candidate.mesh or (SHARD_APUS,)
     apus = 1
     for s in mesh:

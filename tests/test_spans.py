@@ -1,11 +1,13 @@
 """The program's host spans and host round-trip counters.
 
 Spans are read back from a real profiler trace on the CPU: a region call
-opens ``repro:region:<name>`` around ``repro:dispatch`` and ``repro:sync``;
-a sharded replay tags every span of an op with its index, on the overlap
-worker too.  The counters: ``readbacks`` per scalar the solver reads,
-``syncs`` per region call, reset and merged like the other per-run
-numbers.
+opens ``repro:region:<name>`` around ``repro:dispatch``, and around
+``repro:sync`` only when the call is staged or a SyncedExecutor runs
+it; a sharded replay tags every span of an op with its index, on the
+overlap worker too.  The counters:
+``readbacks`` per scalar the solver reads, ``syncs`` per wait that
+happens, reset and merged like the other per-run numbers.  A call the
+Executor does not wait for gives the same bits as one it waits for.
 """
 import glob
 import os
@@ -20,11 +22,12 @@ import pytest
 from repro.cfd import fvm
 from repro.cfd.grid import Grid
 from repro.cfd.precond import rb_dilu_factor
+from repro.cfd.simple import SimpleConfig, SimpleFoam, SimpleState
 from repro.cfd.solvers import make_solver_regions, pbicgstab_regions
 from repro.core.ledger import Ledger, readback, span
 from repro.core.program import capture
-from repro.core.regions import (Executor, HostPolicy, UnifiedPolicy,
-                                region)
+from repro.core.regions import (DiscretePolicy, Executor, HostPolicy,
+                                SyncedExecutor, UnifiedPolicy, region)
 from repro.core.shard_program import shard_program
 from repro.launch.mesh import make_apu_mesh
 
@@ -67,6 +70,7 @@ def test_span_names_and_stats():
 
 
 def test_executor_run_opens_region_dispatch_sync(tmp_path):
+    """An unstaged call is dispatched and not waited for: no ``sync``."""
     ledger = Ledger("spans")
 
     @region("axpy", ledger=ledger)
@@ -80,6 +84,33 @@ def test_executor_run_opens_region_dispatch_sync(tmp_path):
     np.testing.assert_allclose(np.asarray(out), 3.0)
     (reg,) = [e for e in events if e[1] == "repro:region:axpy"]
     assert reg[4] == {"impl": "ref", "target": "default"}
+    (disp,) = [e for e in events if e[1] == "repro:dispatch"]
+    assert inside(disp, reg)
+    assert not [e for e in events if e[1] == "repro:sync"]
+    assert ledger.syncs == 0 and ledger.regions["axpy"].calls == 2
+
+
+@pytest.mark.parametrize("executor, policy, target", [
+    (Executor, DiscretePolicy, "device"),
+    (SyncedExecutor, UnifiedPolicy, "default")])
+def test_waited_call_syncs_inside_its_region(tmp_path, executor, policy,
+                                             target):
+    """The stager reads a staged call's result on the host, so the call
+    waits for it, as a SyncedExecutor waits for every call: ``sync``
+    after ``dispatch``, both inside the region."""
+    ledger = Ledger("waited_spans")
+
+    @region("axpy", ledger=ledger)
+    def axpy(a, x, y):
+        return a * x + y
+
+    ex = executor(policy(), ledger)
+    x = jnp.ones(64)
+    ex.run(axpy, 2.0, x, x)                   # compile outside the trace
+    out, events = traced(lambda: ex.run(axpy, 2.0, x, x), tmp_path)
+    np.testing.assert_allclose(np.asarray(out), 3.0)
+    (reg,) = [e for e in events if e[1] == "repro:region:axpy"]
+    assert reg[4] == {"impl": "ref", "target": target}
     (disp,) = [e for e in events if e[1] == "repro:dispatch"]
     (sync,) = [e for e in events if e[1] == "repro:sync"]
     assert inside(disp, reg) and inside(sync, reg)
@@ -103,7 +134,45 @@ def test_pbicgstab_counts_readbacks_and_syncs(iters):
     # two norms before the loop; rho, denom, tt, ts and the residual in it
     assert ledger.readbacks == 2 + 5 * iters
     calls = sum(r.calls for r in ledger.regions.values())
-    assert ledger.syncs == calls == 4 + 13 * iters
+    assert calls == 4 + 13 * iters
+    assert ledger.syncs == 0                  # the readbacks wait instead
+
+
+def solve_8(executor):
+    g = Grid((8, 8, 8))
+    A, _ = fvm.laplacian(g, 1.0)
+    b = jnp.asarray(np.random.RandomState(1).rand(*g.shape)
+                    .astype(np.float32))
+    red, _ = g.red_black_masks()
+    P = rb_dilu_factor(A, red)
+    res = pbicgstab_regions(executor, make_solver_regions(executor.ledger),
+                            A, b, jnp.zeros_like(b), P, tol=0.0, max_iter=3)
+    assert res.iters == 3
+    return [res.x], [res.initial_residual, res.final_residual]
+
+
+def time_step_8(executor):
+    cfg = SimpleConfig(grid=Grid((8, 8, 8)), inner_max=6)
+    rng = np.random.RandomState(2)
+    st = SimpleState(*(jnp.asarray(1e-3 * rng.randn(8, 8, 8)
+                                   .astype(np.float32)) for _ in range(4)))
+    new, m = SimpleFoam(cfg).time_step(st, executor=executor)
+    return [new.u, new.v, new.w, new.p], [m["res_u"], m["res_p"]]
+
+
+@pytest.mark.parametrize("work", [solve_8, time_step_8])
+def test_unsynced_calls_give_the_same_bits(work):
+    unsynced = Executor(UnifiedPolicy(), Ledger("unsynced"))
+    synced = SyncedExecutor(UnifiedPolicy(), Ledger("synced"))
+    fields, scalars = work(unsynced)
+    fields_s, scalars_s = work(synced)
+    for a, b in zip(fields, fields_s):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert scalars == scalars_s
+    assert unsynced.ledger.syncs == 0
+    assert synced.ledger.syncs == sum(
+        r.calls for r in synced.ledger.regions.values()) > 0
+    assert unsynced.ledger.readbacks == synced.ledger.readbacks > 0
 
 
 def test_pbicgstab_reads_back_inside_the_solve(tmp_path):
@@ -197,12 +266,25 @@ def test_sharded_replay_tags_every_span_with_its_op(tmp_path):
     assert led.syncs == sum(r.calls for r in led.regions.values()) == 5
 
 
-def test_host_routed_replay_counts_its_sync():
+@pytest.mark.parametrize("policy, syncs_per_call",
+                         [(HostPolicy, 0), (DiscretePolicy, 1)])
+def test_host_routed_replay_counts_its_sync(policy, syncs_per_call):
+    """Single calls fall back to the Executor, which waits only for a
+    staged call (DiscretePolicy), not for a host-routed one (HostPolicy);
+    the replay waits for every op (and halo exchange) it records, on the
+    host route or the device's."""
     prog, x = stencil_program(Ledger("host_spans"))
-    sp = shard_program(prog, make_apu_mesh(1), HostPolicy(), overlap=False)
-    sp.replay(x)
+    sp = shard_program(prog, make_apu_mesh(1), policy(), overlap=False)
     host = sp.executor.host_ledger
-    assert host.syncs == sum(r.calls for r in host.regions.values()) == 3
+    stencil, _, scale = (op.region for op in prog.ops)
+    np.asarray(sp.run(scale, sp.run(stencil, sp.run(stencil, x))))
+    calls = sum(r.calls for r in host.regions.values())
+    assert calls == 3 and host.syncs == syncs_per_call * calls
+    host.reset_timings()
+    sp.replay(x)
+    ledgers = (*sp.ledgers, host)
+    assert sum(led.syncs for led in ledgers) == sum(
+        r.calls for led in ledgers for r in led.regions.values()) >= 3
 
 
 def test_every_program_span_goes_through_the_helper():

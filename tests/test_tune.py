@@ -261,7 +261,7 @@ def test_cost_model_rank_correlation_on_cfd_corpus():
     w = get_workload("cfd_step")
     model = TU.model_costs(w.build_program())
     assert not model["skipped"], model["skipped"]
-    res = w.run(PolicyCandidate(), 2)
+    res = w.run(PolicyCandidate(), 2, calibrate=True)
     common = [r for r in res.region_s if r in model["region_s"]]
     assert len(common) >= 8, common
     m = np.array([model["region_s"][r] for r in common])
@@ -269,3 +269,63 @@ def test_cost_model_rank_correlation_on_cfd_corpus():
     rank = lambda v: np.argsort(np.argsort(v))
     corr = float(np.corrcoef(rank(m), rank(s))[0, 1])
     assert corr > 0.5, (corr, dict(zip(common, zip(m, s))))
+
+
+@pytest.mark.parametrize("placement", ["unified", "discrete"])
+def test_region_seconds_come_from_synced_calls(monkeypatch, placement):
+    """The hot path's Executor waits only for a staged call; the
+    calibration replays the candidate's policy through a SyncedExecutor,
+    which waits for every call, and a staged call's copies stay out of
+    the region seconds."""
+    import time
+
+    from repro.tune import workloads as WL
+    slow = 0.2                                # seconds each copy-out takes
+    prog = _mini_program()
+    d, x, b = prog._example_in_leaves
+    ex = WL._executor(PolicyCandidate(placement=placement), None, None,
+                      "tune_synced")
+    prog.replay(ex, d, x, b)                  # compile outside the count
+    staged = ex.policy.stager.stages
+    assert ex.ledger.syncs == (2 if staged else 0)
+    if staged:
+        stage_out = ex.policy.stager.stage_out
+
+        def slow_stage_out(*args):
+            out, s, nbytes = stage_out(*args)
+            time.sleep(slow)
+            return out, s + slow, nbytes
+
+        monkeypatch.setattr(ex.policy.stager, "stage_out", slow_stage_out)
+    replays = []
+    region_s = WL._region_seconds(
+        ex, lambda e: replays.append((e, prog.replay(e, d, x, b))), True)
+    ((synced, out),) = replays
+    np.testing.assert_allclose(np.asarray(out), np.asarray(b - 0.3 * d),
+                               rtol=1e-6)
+    rows = synced.ledger.regions
+    assert synced.ledger.syncs == sum(r.calls for r in rows.values()) == 2
+    assert sorted(region_s) == ["TSAXPY", "TSCALE"]
+    assert all(t > 0 for t in region_s.values())
+    staging = sum(r.staging_s for r in rows.values())
+    assert staging >= (2 * slow if staged else 0.0)
+    assert sum(region_s.values()) < slow, (region_s, staging)
+    assert WL._region_seconds(ex, lambda e: replays.append(e), False) == {}
+    assert len(replays) == 1                  # no calibration, no replay
+
+
+def test_tune_calibrates_on_the_reference_run_only():
+    """Only the reference run measures region seconds (on the synced
+    path); every finalist's FOM is taken the way the hot path runs."""
+    w = _fake_workload({"discrete": 2.0, "unified": 1.0})
+    fake_run, seen = w.run, []
+
+    def run(candidate, steps, winners=None, calibrate=False):
+        seen.append((candidate, calibrate))
+        return fake_run(candidate, steps, winners)
+
+    w.run = run
+    TU.tune(w, trials=3)
+    assert seen[0] == (w.ref, True)
+    assert len(seen) > 1
+    assert all(c != w.ref and not cal for c, cal in seen[1:])
