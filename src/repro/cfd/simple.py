@@ -216,9 +216,11 @@ class SimpleFoam:
 
         The step executes eagerly during capture (inner solver loops run to
         their real convergence on ``st``), and the resulting trace — with
-        iteration counts and host-extracted residual scalars frozen,
-        CUDA-graph style — can be replayed under any policy, overlapped by
-        ``AsyncExecutor``, or vmapped over N cavities by ``replay_batch``.
+        inner iteration counts frozen, CUDA-graph style, and the Krylov
+        scalars recorded as dataflow between regions — can be replayed
+        under any policy, overlapped by ``AsyncExecutor``, or vmapped over
+        N cavities by ``replay_batch``.  A replay from another state is a
+        real SIMPLE iteration at the captured iteration counts.
         """
         from repro.core.program import capture
 

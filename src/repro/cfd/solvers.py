@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,16 +34,35 @@ SMALL = 1e-20
 
 @dataclasses.dataclass
 class SolveResult:
+    """``iters`` and the residuals are host numbers where a tolerance
+    controlled the loop, and 0-d device arrays where it did not: no field
+    is read back inside the solve."""
     x: jax.Array
-    iters: int
-    initial_residual: float
-    final_residual: float
-    converged: bool
+    iters: Any
+    initial_residual: Any
+    final_residual: Any
+    tol: float
+
+    @property
+    def converged(self):
+        return self.final_residual <= self.tol
 
 
 # ---------------------------------------------------------------------------
 # Region-granular PBiCGStab (paper-faithful execution)
 # ---------------------------------------------------------------------------
+
+def _ratio(num, den):
+    """alpha = rho / denom and omega = ts / tt: a denominator with
+    ``|den| <= SMALL`` reads as SMALL."""
+    return num / jnp.where(jnp.abs(den) > SMALL, den, SMALL)
+
+
+def _factor(rho, num, den):
+    """``_ratio`` as an update's scale, 0 once ``|rho| < SMALL``: an
+    iteration whose rho broke down leaves x and r as they were."""
+    return jnp.where(jnp.abs(rho) < SMALL, 0, _ratio(num, den))
+
 
 def make_solver_regions(ledger: Optional[Ledger] = None):
     # fresh Ledger when none given — repeated factory calls must not grow
@@ -70,26 +89,33 @@ def make_solver_regions(ledger: Optional[Ledger] = None):
 
     precond_r.variant("pallas", rb_dilu_pallas)
 
+    # the Krylov scalars arrive as 0-d arrays; each region works out the
+    # alpha, beta or omega it uses from the scalars they come from
     @region("sA=rA-alpha*AyA", **kw)
-    def saxpy_r(a, x, y):
-        return y - a * x
+    def saxpy_r(rho, num, den, x, y):
+        return y - _factor(rho, num, den) * x
 
     @saxpy_r.variant("pallas")
-    def _saxpy_k(a, x, y):
+    def _saxpy_k(rho, num, den, x, y):
         # y - a*x is fused_axpy with the scale negated (exact)
-        return fused_axpy_pallas(-a, x, y)
+        return fused_axpy_pallas(-_factor(rho, num, den), x, y)
 
     @region("x+=a*yA+w*zA", **kw)
-    def update_x_r(x, a, yA, w, zA):
-        return x + a * yA + w * zA
+    def update_x_r(x, rho, denom, yA, ts, tt, zA):
+        return x + _factor(rho, rho, denom) * yA + _factor(rho, ts, tt) * zA
 
     @update_x_r.variant("pallas")
-    def _update_x_k(x, a, yA, w, zA):
-        return fused_axpbypz_pallas(a, yA, w, zA, x)
+    def _update_x_k(x, rho, denom, yA, ts, tt, zA):
+        return fused_axpbypz_pallas(_factor(rho, rho, denom), yA,
+                                    _factor(rho, ts, tt), zA, x)
 
     @region("p=r+beta*(p-w*v)", **kw)
-    def update_p_r(r, beta, p, w, v):
-        return r + beta * (p - w * v)
+    def update_p_r(r, rho, rho_old, denom, ts, tt, p, v):
+        # alpha and omega of the last iteration, from its rho, denom, ts, tt
+        omega = _ratio(ts, tt)
+        beta = (rho / rho_old) * (_ratio(rho_old, denom)
+                                  / jnp.maximum(omega, SMALL))
+        return jnp.where(jnp.abs(rho) < SMALL, p, r + beta * (p - omega * v))
 
     @region("dot", **kw)
     def dot_r(x, y):
@@ -99,10 +125,19 @@ def make_solver_regions(ledger: Optional[Ledger] = None):
     def summag_r(x):
         return jnp.sum(jnp.abs(x.astype(jnp.float64)))
 
+    @region("res=sumMag(r)/norm", **kw)
+    def residual_r(r, norm, rho, it):
+        """The normalised residual; the iteration count, advanced unless
+        rho broke down; and what a tolerance-controlled loop reads: the
+        residual, or -1 once rho broke down."""
+        live = ~(jnp.abs(rho) < SMALL)      # the freeze's test, NaN live
+        res = jnp.sum(jnp.abs(r.astype(jnp.float64))) / (norm + SMALL)
+        return res, it + live, jnp.where(live, res, -1)
+
     class R:
         amul, precond = amul_r, precond_r
         saxpy, update_x, update_p = saxpy_r, update_x_r, update_p_r
-        dot, summag = dot_r, summag_r
+        dot, summag, residual = dot_r, summag_r, residual_r
 
     return R
 
@@ -110,54 +145,70 @@ def make_solver_regions(ledger: Optional[Ledger] = None):
 def pbicgstab_regions(executor, regions, A: DiaMatrix, b, x0, P: RBDilu,
                       tol: float = 1e-6, rel_tol: float = 0.0,
                       max_iter: int = 500) -> SolveResult:
-    """OpenFOAM PBiCGStab, one executor.run per offloaded region.  Every
-    scalar the host reads back goes through ``readback``, counted on the
-    executor's ledger where it has one."""
+    """OpenFOAM PBiCGStab, one executor.run per offloaded region.
+
+    The Krylov scalars stay 0-d device arrays between the regions. An
+    iteration whose rho broke down (``|rho| < SMALL``) leaves x, r and p
+    as they were, so the next sees the same rho and changes nothing
+    either. The host reads only what controls the loop, each read through
+    ``readback`` (counted on the executor's ledger where it has one): with
+    ``tol <= 0`` and ``rel_tol <= 0`` nothing, and the solve runs
+    ``max_iter`` iterations; else the entry residual, then one value an
+    iteration that carries the residual and the breakdown."""
     with span(SOLVE):
         run = executor.run
         ledger = getattr(executor, "ledger", None)
-
-        def read(x) -> float:
-            return readback(x, ledger)
-
+        controlled = tol > 0 or rel_tol > 0
+        # in the dtype the dot region returns; region-made from here on
+        one = jnp.ones((), jax.dtypes.canonicalize_dtype(jnp.float64))
+        it = jnp.zeros((), jnp.int32)
         x = x0
         # r = b - 1.0*Ax through the saxpy region (identical math) so the
         # whole residual dataflow is region-visible — program capture
         # (repro.core.program) records real dependencies instead of
         # freezing a host-computed array as a constant
-        r = run(regions.saxpy, 1.0, run(regions.amul, A.diag, A.off, x), b)
+        r = run(regions.saxpy, one, one, one,
+                run(regions.amul, A.diag, A.off, x), b)
         rA0 = r
-        norm = read(run(regions.summag, b)) + SMALL
-        res0 = read(run(regions.summag, r)) / norm
+        norm = run(regions.summag, b)
+        res0, _, _ = run(regions.residual, r, norm, one, it)
         res = res0
-        rho_old = alpha = omega = 1.0
+        if controlled:
+            res0 = res = readback(res0, ledger)
+        # the last iteration's rho, denom, ts and tt give beta its alpha
+        # and omega: 1.0 each before the first
+        rho_old = denom = ts = tt = one
         # zeros in device memory whatever space b was staged into
         p = jnp.zeros(b.shape, b.dtype)
         v = jnp.zeros(b.shape, b.dtype)
-        it = 0
-        while res > tol and it < max_iter and (
-                rel_tol <= 0 or res / max(res0, SMALL) > rel_tol):
-            rho = read(run(regions.dot, rA0, r))
-            if abs(rho) < SMALL:
-                break
-            beta = (rho / rho_old) * (alpha / max(omega, SMALL))
-            p = run(regions.update_p, r, beta, p, omega, v)
+        n = 0
+        while n < max_iter and (not controlled or (res > tol and (
+                rel_tol <= 0 or res / max(res0, SMALL) > rel_tol))):
+            rho = run(regions.dot, rA0, r)
+            p = run(regions.update_p, r, rho, rho_old, denom, ts, tt, p, v)
             yA = run(regions.precond, P.rdiag, P.red, A.off, p)
             v = run(regions.amul, A.diag, A.off, yA)
-            denom = read(run(regions.dot, rA0, v))
-            alpha = rho / (denom if abs(denom) > SMALL else SMALL)
-            s = run(regions.saxpy, alpha, v, r)
+            denom = run(regions.dot, rA0, v)
+            s = run(regions.saxpy, rho, rho, denom, v, r)
             zA = run(regions.precond, P.rdiag, P.red, A.off, s)
             t = run(regions.amul, A.diag, A.off, zA)
-            tt = read(run(regions.dot, t, t))
-            ts = read(run(regions.dot, t, s))
-            omega = ts / (tt if abs(tt) > SMALL else SMALL)
-            x = run(regions.update_x, x, alpha, yA, omega, zA)
-            r = run(regions.saxpy, omega, t, s)
+            tt = run(regions.dot, t, t)
+            ts = run(regions.dot, t, s)
+            x = run(regions.update_x, x, rho, denom, yA, ts, tt, zA)
+            r = run(regions.saxpy, rho, ts, tt, t, s)
             rho_old = rho
-            res = read(run(regions.summag, r)) / norm
-            it += 1
-        return SolveResult(x, it, res0, res, res <= tol)
+            res_d, it, signal = run(regions.residual, r, norm, rho, it)
+            if controlled:
+                value = readback(signal, ledger)
+                if value < 0:
+                    break           # rho broke down: nothing changed
+                res = value
+            else:
+                res = res_d
+            n += 1
+        if controlled:
+            it = n
+        return SolveResult(x, it, res0, res, tol)
 
 
 # ---------------------------------------------------------------------------
@@ -217,4 +268,4 @@ def solve(A: DiaMatrix, b, x0, red, tol=1e-6, max_iter=500, use_dilu=True):
     P = rb_dilu_factor(A, red)
     x, it, r0, res = pbicgstab_fused(A, b, x0, P.rdiag, P.red, tol=tol,
                                      max_iter=max_iter, use_dilu=use_dilu)
-    return SolveResult(x, int(it), float(r0), float(res), float(res) <= tol)
+    return SolveResult(x, int(it), float(r0), float(res), tol)

@@ -11,7 +11,8 @@ from repro.cfd.grid import Grid
 from repro.cfd.precond import (dilu_seq_ref, jacobi_apply, rb_dilu_apply,
                                rb_dilu_factor)
 from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
-from repro.cfd.solvers import make_solver_regions, pbicgstab_regions, solve
+from repro.cfd.solvers import (make_solver_regions, pbicgstab_fused,
+                               pbicgstab_regions, solve)
 from repro.core.ledger import Ledger
 from repro.core.regions import (DiscretePolicy, Executor, HostPolicy,
                                 UnifiedPolicy)
@@ -102,6 +103,90 @@ def test_pbicgstab_regions_matches_fused(rng):
     assert r1.converged and r2.converged
     np.testing.assert_allclose(np.asarray(r1.x), np.asarray(r2.x),
                                rtol=5e-3, atol=5e-4)
+
+
+def laplacian_8(rng):
+    g = Grid((8, 8, 8))
+    A, _ = fvm.laplacian(g, 1.0)
+    red, _ = g.red_black_masks()
+    b = jnp.asarray(rng.rand(*g.shape).astype(np.float32))
+    return A, b, rb_dilu_factor(A, red)
+
+
+@pytest.mark.parametrize("case", ["zero", "consistent"])
+def test_pbicgstab_regions_freezes_on_breakdown(rng, case):
+    """rho is 0 in the first iteration (r = b - A x0 = 0): no tolerance
+    stops the loop, yet every iteration leaves x as it was and counts
+    nothing."""
+    A, _, P = laplacian_8(rng)
+    ex = Executor(UnifiedPolicy(), Ledger("t"))
+    regions = make_solver_regions(ex.ledger)
+    if case == "zero":
+        x0 = jnp.zeros(A.diag.shape, jnp.float32)
+        b = jnp.zeros_like(x0)
+    else:
+        x0 = jnp.asarray(rng.randn(*A.diag.shape).astype(np.float32))
+        b = ex.run(regions.amul, A.diag, A.off, x0)   # the same executable
+    r = pbicgstab_regions(ex, regions, A, b, x0, P, tol=0.0, max_iter=3)
+    np.testing.assert_array_equal(np.asarray(r.x), np.asarray(x0))
+    assert int(r.iters) == 0
+    assert float(r.final_residual) == float(r.initial_residual) == 0.0
+    assert ex.ledger.readbacks == 0
+
+
+class _RhoBreaksDown:
+    """Forwards to an Executor, but every ``dot`` that makes rho from
+    iteration ``at`` (1-based) on returns 0."""
+
+    def __init__(self, ex, regions, at):
+        self.ex, self.ledger, self.dot = ex, ex.ledger, regions.dot
+        self.dots, self.at = 0, at
+
+    def run(self, region, *args):
+        out = self.ex.run(region, *args)
+        if region is self.dot:
+            self.dots += 1
+            if self.dots > 4 * (self.at - 1) and self.dots % 4 == 1:
+                return jnp.zeros_like(out)
+        return out
+
+
+@pytest.mark.parametrize("tol", [0.0, 1e-12])
+def test_pbicgstab_regions_breakdown_mid_solve(rng, tol):
+    """rho breaks down in the second iteration: the solve ends with the
+    iterate, residual and count of the first. Under a tolerance the host
+    stops after that iteration; without one the device freezes every
+    iteration left."""
+    A, b, P = laplacian_8(rng)
+    ex = Executor(UnifiedPolicy(), Ledger("t"))
+    regions = make_solver_regions(ex.ledger)
+    x0 = jnp.zeros_like(b)
+    one = pbicgstab_regions(ex, regions, A, b, x0, P, tol=tol, max_iter=1)
+    broken = _RhoBreaksDown(Executor(UnifiedPolicy(), Ledger("b")),
+                            regions, at=2)
+    r = pbicgstab_regions(broken, regions, A, b, x0, P, tol=tol,
+                          max_iter=5)
+    assert int(r.iters) == 1
+    assert float(r.final_residual) == float(one.final_residual)
+    np.testing.assert_array_equal(np.asarray(r.x), np.asarray(one.x))
+    if tol > 0:       # entry, then two iterations
+        assert broken.dots == 8 and broken.ledger.readbacks == 1 + 2
+    else:
+        assert broken.dots == 4 * 5 and broken.ledger.readbacks == 0
+
+
+def test_pbicgstab_regions_stops_where_fused_does(rng):
+    A, b, P = laplacian_8(rng)
+    ex = Executor(UnifiedPolicy(), Ledger("t"))
+    regions = make_solver_regions(ex.ledger)
+    x0 = jnp.zeros_like(b)
+    r1 = pbicgstab_regions(ex, regions, A, b, x0, P, tol=1e-6)
+    x2, it2, _, res2 = pbicgstab_fused(A, b, x0, P.rdiag, P.red, tol=1e-6)
+    assert r1.iters == int(it2) > 1
+    assert r1.converged and float(res2) <= 1e-6
+    assert ex.ledger.readbacks == 1 + r1.iters
+    np.testing.assert_allclose(np.asarray(r1.x), np.asarray(x2),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_executors_same_result(rng):

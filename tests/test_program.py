@@ -174,6 +174,37 @@ def test_cavity_step_capture_parity():
     assert rep["staging_saved_s"] > 0
 
 
+def test_cavity_capture_keeps_krylov_scalars_as_dataflow():
+    """The solver's Krylov scalars stay region outputs, so capture records
+    them as Ref edges (no host float frozen as a Lit) and a chained replay
+    computes real SIMPLE iterations: two replayed steps match two eager
+    ones."""
+    from repro.cfd.grid import Grid
+    from repro.cfd.simple import SimpleConfig, SimpleFoam, init_state
+    cfg = SimpleConfig(grid=Grid((8, 8, 8)), nu=0.1, tol_u=0.0, tol_p=0.0,
+                       inner_max=4)
+    app = SimpleFoam(cfg)
+    st, _, _ = app.run_steps(init_state(cfg), 1)
+    prog = app.capture_step(st)
+    lits = [d.value for op in prog.ops for d in op.leaves
+            if isinstance(d, Lit)]
+    assert not any(isinstance(v, (float, np.floating)) for v in lits)
+    reductions = {k for k, op in enumerate(prog.ops)
+                  if op.region.name.split("#")[0] in ("dot", "sumMag")}
+    consumed = {d.op for op in prog.ops for d in op.leaves
+                if isinstance(d, Ref)}
+    # four solves, each a |b| sum and four dots an iteration
+    assert len(reductions) == 4 * (1 + 4 * 4) and reductions <= consumed
+    replayed, _ = app.replay_steps(prog, st, 2, Executor(UnifiedPolicy()))
+    eager = st
+    for _ in range(2):
+        eager, _ = app.time_step(eager)
+    for a, b in zip((replayed.u, replayed.v, replayed.w, replayed.p),
+                    (eager.u, eager.v, eager.w, eager.p)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # PR-2 flake regression: buffer-aliasing/recycling race
 # ---------------------------------------------------------------------------

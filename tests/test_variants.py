@@ -38,6 +38,10 @@ ALL_POLICIES = [UnifiedPolicy, HostPolicy, DiscretePolicy,
                 lambda **kw: AdaptivePolicy(cutoff=64, **kw)]
 
 
+def scalars(*values):
+    return tuple(jnp.float32(v) for v in values)
+
+
 def solver_fixture():
     """Every variant-carrying region of the CFD stack with example args:
     [(region, make_args())] over a real assembled system."""
@@ -54,8 +58,11 @@ def solver_fixture():
     return [
         (R.amul, (A.diag, A.off, x)),
         (R.precond, (P.rdiag, P.red, A.off, x)),
-        (R.saxpy, (0.7, x, y)),
-        (R.update_x, (x, 0.3, y, -0.2, z)),
+        # Krylov scalars as the solver passes them: 0-d float32 arrays
+        (R.saxpy, scalars(0.5, 0.7, 2.0) + (x, y)),
+        (R.saxpy, scalars(0.0, 0.7, 2.0) + (x, y)),     # rho broke down
+        (R.update_x, (x,) + scalars(0.3, 1.5) + (y,) + scalars(-0.2, 0.5)
+         + (z,)),
         (ops.axpy, (1.5, x, y)),
         (ops.xpay, (-0.5, x, y)),
         (ops.axpbypz, (0.25, x, -1.5, y, z)),
